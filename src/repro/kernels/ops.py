@@ -1,9 +1,10 @@
 """jit'd public wrappers for the Pallas kernels.
 
-On non-TPU backends (this CPU container) the kernels execute with
-``interpret=True`` — the kernel body runs step-by-step on CPU, validating
-BlockSpec indexing and the numerical algorithm against ``ref.py``.
-On TPU the same call sites compile to Mosaic.
+On the CPU backend the kernels execute with ``interpret=True`` — the
+kernel body runs step-by-step on CPU, validating BlockSpec indexing and
+the numerical algorithm against ``ref.py``.  Everywhere else the same
+call sites compile to Mosaic, so a backend Mosaic cannot target fails
+loudly instead of silently interpreting.
 """
 from __future__ import annotations
 
@@ -18,7 +19,7 @@ from . import ssd_scan as _ssd
 
 
 def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    return jax.default_backend() == "cpu"
 
 
 def flash_attention(q, k, v, *, causal: bool = True,

@@ -27,116 +27,99 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.flash_attention import (NEG_INF, _pad_to,
+from repro.kernels.flash_attention import (DIM_SEMANTICS, NEG_INF,
+                                           block_sizes, heads_major,
+                                           init_softmax_state,
+                                           online_softmax_step,
                                            _validate_attn_shapes)
 
 
-def _partial_kernel(delta_ref, q_ref, k_ref, v_ref,
-                    acc_ref, m_ref, l_ref, *, scale: float, causal: bool,
+def _partial_kernel(delta_ref, q_ref, k_ref, v_ref, acc_ref, m_ref, l_ref,
+                    acc_s, m_s, l_s, *, scale: float, causal: bool,
                     window: Optional[int], block_q: int, block_k: int,
-                    seq_k: int, kv_len: int):
-    # delta_ref: (1, 1) int32 — q_start − k_start in global positions.
-    # Outputs are the raw online-softmax state: acc (block_q, dh) fp32,
-    # m / l (block_q, 1) fp32.  Rows the mask fully rejects keep
-    # m == NEG_INF, l == 0, acc == 0, which the cross-round merge and the
-    # final normalization treat as an exact zero contribution.
-    iq = pl.program_id(2)
-    delta = delta_ref[0, 0]
-    q = q_ref[...].astype(jnp.float32) * scale
-    q_pos = (iq * block_q + delta
-             + jax.lax.broadcasted_iota(jnp.int32, (block_q, 1), 0))
+                    kv_len: Optional[int]):
+    # delta_ref: (1,) int32 in SMEM — q_start − k_start in global
+    # positions.  Outputs are the raw online-softmax state: acc
+    # (block_q, dh) fp32, m / l (block_q, 1) fp32.  Rows the mask fully
+    # rejects keep m == NEG_INF, l == 0, acc == 0, which the cross-round
+    # merge and the final normalization treat as an exact zero
+    # contribution.
+    iq, ik = pl.program_id(2), pl.program_id(3)
+    delta = delta_ref[0]
 
-    n_k = seq_k // block_k
+    @pl.when(ik == 0)
+    def _():
+        init_softmax_state(acc_s, m_s, l_s)
 
-    def body(ik, carry):
-        acc, m_prev, l_prev = carry
-        k = pl.load(k_ref, (pl.dslice(ik * block_k, block_k), slice(None)))
-        v = pl.load(v_ref, (pl.dslice(ik * block_k, block_k), slice(None)))
-        s = q @ k.astype(jnp.float32).T                       # (bq, bk)
-        k_pos = ik * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (1, block_k), 1)
-        mask = jnp.ones((block_q, block_k), bool)
-        if kv_len < seq_k:
-            mask &= k_pos < kv_len
-        if causal:
-            mask &= k_pos <= q_pos
-        if window is not None:
-            mask &= k_pos > q_pos - window
-        s = jnp.where(mask, s, NEG_INF)
-        m_cur = jnp.max(s, axis=-1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_cur)
-        p = jnp.where(s > NEG_INF * 0.5, jnp.exp(s - m_new), 0.0)
-        alpha = jnp.exp(m_prev - m_new)
-        l_new = alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
-        acc = acc * alpha + p @ v.astype(jnp.float32)
-        return acc, m_new, l_new
+    # q/k positions on the visiting panel's local axis: k_global <=
+    # q_global is exactly k_local <= q_local + delta
+    q_lo, k_lo = iq * block_q + delta, ik * block_k
+    live = []
+    if causal:          # delta is dynamic, so the tile skip is too
+        live.append(k_lo <= q_lo + block_q - 1)
+    if window is not None:
+        live.append(k_lo + block_k - 1 > q_lo - window)
 
-    dh = q_ref.shape[-1]
-    init = (jnp.zeros((block_q, dh), jnp.float32),
-            jnp.full((block_q, 1), NEG_INF, jnp.float32),
-            jnp.zeros((block_q, 1), jnp.float32))
-    # delta is dynamic (it changes per ring round), so no static block
-    # skipping here — masking alone decides admissibility.
-    acc, m, l = jax.lax.fori_loop(0, n_k, body, init)
-    acc_ref[...] = acc
-    m_ref[...] = m
-    l_ref[...] = l
+    def step():
+        q_pos = q_lo + jax.lax.broadcasted_iota(jnp.int32, (block_q, 1), 0)
+        k_pos = k_lo + jax.lax.broadcasted_iota(jnp.int32, (1, block_k), 1)
+        online_softmax_step(q_ref, k_ref, v_ref, acc_s, m_s, l_s,
+                            q_pos=q_pos, k_pos=k_pos, scale=scale,
+                            causal=causal, window=window, kv_len=kv_len)
+
+    if live:
+        pl.when(functools.reduce(jnp.logical_and, live))(step)
+    else:
+        step()
+
+    @pl.when(ik == pl.num_programs(3) - 1)
+    def _():
+        acc_ref[...] = acc_s[...]
+        m_ref[...] = m_s[...]
+        l_ref[...] = l_s[...]
 
 
 def _flash_partial(q: jax.Array, k: jax.Array, v: jax.Array,
-                   delta: jax.Array, *, causal: bool,
+                   delta: jax.Array, *, kv_len: int, causal: bool,
                    window: Optional[int], block_q: int, block_k: int,
                    interpret: bool) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """One panel visit: (acc, m, l) of local q against one K/V panel."""
-    B, S, H, dh = q.shape
-    T, KV = k.shape[1], k.shape[2]
-    G = H // KV
-    block_q = min(block_q, -(-S // 8) * 8)
-    block_k = min(block_k, -(-T // 8) * 8)
-    S_pad = -(-S // block_q) * block_q
-    T_pad = -(-T // block_k) * block_k
-    q = _pad_to(q, 1, S_pad)
-    k = _pad_to(k, 1, T_pad)
-    v = _pad_to(v, 1, T_pad)
-    delta = jnp.reshape(delta, (1, 1)).astype(jnp.int32)
+    """One panel visit: (acc, m, l) of local q against one K/V panel.
 
-    grid = (B, H, S_pad // block_q)
+    Heads-major operands: q (B, H, S_pad, dh) and k/v (B, KV, T_pad, dh),
+    already padded to whole blocks; ``block_k`` tiles T_pad exactly and
+    only the first ``kv_len`` keys of the panel are real."""
+    B, H, S_pad, dh = q.shape
+    KV, T_pad = k.shape[1], k.shape[2]
+    G = H // KV
     kernel = functools.partial(
         _partial_kernel, scale=1.0 / (dh ** 0.5), causal=causal,
-        window=window, block_q=block_q, block_k=block_k, seq_k=T_pad,
-        kv_len=T)
-
-    acc, m, l = pl.pallas_call(
+        window=window, block_q=block_q, block_k=block_k,
+        kv_len=kv_len if kv_len < T_pad else None)
+    q_spec = pl.BlockSpec((None, None, block_q, dh),
+                          lambda b, h, i, j, d: (b, h, i, 0))
+    kv_spec = pl.BlockSpec((None, None, block_k, dh),
+                           lambda b, h, i, j, d: (b, h // G, j, 0))
+    row_spec = pl.BlockSpec((None, None, block_q, 1),
+                            lambda b, h, i, j, d: (b, h, i, 0))
+    return pl.pallas_call(
         kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, 1), lambda b, h, i: (0, 0)),
-            pl.BlockSpec((None, block_q, None, dh),
-                         lambda b, h, i: (b, i, h, 0)),
-            pl.BlockSpec((None, T_pad, None, dh),
-                         lambda b, h, i, G=G: (b, 0, h // G, 0)),
-            pl.BlockSpec((None, T_pad, None, dh),
-                         lambda b, h, i, G=G: (b, 0, h // G, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((None, block_q, None, dh),
-                         lambda b, h, i: (b, i, h, 0)),
-            pl.BlockSpec((None, block_q, None, 1),
-                         lambda b, h, i: (b, i, h, 0)),
-            pl.BlockSpec((None, block_q, None, 1),
-                         lambda b, h, i: (b, i, h, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((B, S_pad, H, dh), jnp.float32),
-            jax.ShapeDtypeStruct((B, S_pad, H, 1), jnp.float32),
-            jax.ShapeDtypeStruct((B, S_pad, H, 1), jnp.float32),
-        ],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(B, H, S_pad // block_q, T_pad // block_k),
+            in_specs=[q_spec, kv_spec, kv_spec],
+            out_specs=[q_spec, row_spec, row_spec],
+            scratch_shapes=[pltpu.VMEM((block_q, dh), jnp.float32),
+                            pltpu.VMEM((block_q, 1), jnp.float32),
+                            pltpu.VMEM((block_q, 1), jnp.float32)]),
+        out_shape=[jax.ShapeDtypeStruct((B, H, S_pad, dh), jnp.float32),
+                   jax.ShapeDtypeStruct((B, H, S_pad, 1), jnp.float32),
+                   jax.ShapeDtypeStruct((B, H, S_pad, 1), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=DIM_SEMANTICS),
         interpret=interpret,
-    )(delta, q, k, v)
-    if S_pad != S:
-        acc, m, l = acc[:, :S], m[:, :S], l[:, :S]
-    return acc, m, l
+    )(jnp.reshape(delta, (1,)).astype(jnp.int32), q, k, v)
 
 
 def _merge(state, part):
@@ -168,9 +151,10 @@ def ring_flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
 
     P − 1 ``ppermute`` rounds rotate the K/V panels; each round's
     hand-off is issued before its compute so the collective overlaps the
-    kernel (the pipeline runtime's hand-off idiom).  Causally dead
-    visits (a panel entirely in this shard's future) still run but
-    contribute an all-masked zero state — the merge ignores them.
+    kernel (the pipeline runtime's hand-off idiom).  On causally dead
+    visits (a panel entirely in this shard's future) every tile skips
+    its compute and the visit contributes an all-masked zero state —
+    the merge ignores it.
     """
     P = int(axis_size)
     B, S_loc, H, dh = q.shape
@@ -182,14 +166,17 @@ def ring_flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                                block_q=block_q, block_k=block_k,
                                interpret=interpret)
 
+    block_q, block_k, S_pad, T_pad = block_sizes(S_loc, T_loc, block_q,
+                                                 block_k)
     idx = jax.lax.axis_index(axis_name)
     q_start = idx * S_loc
     perm = [(i, (i + 1) % P) for i in range(P)]
 
-    state = (jnp.zeros((B, S_loc, H, dh), jnp.float32),
-             jnp.full((B, S_loc, H, 1), NEG_INF, jnp.float32),
-             jnp.zeros((B, S_loc, H, 1), jnp.float32))
-    k_cur, v_cur = k, v
+    qh = heads_major(q, S_pad)
+    state = (jnp.zeros((B, H, S_pad, dh), jnp.float32),
+             jnp.full((B, H, S_pad, 1), NEG_INF, jnp.float32),
+             jnp.zeros((B, H, S_pad, 1), jnp.float32))
+    k_cur, v_cur = heads_major(k, T_pad), heads_major(v, T_pad)
     for r in range(P):
         if r < P - 1:
             # hand-off overlap: rotate the panel we already consumed a
@@ -199,8 +186,8 @@ def ring_flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
             v_nxt = jax.lax.ppermute(v_cur, axis_name, perm)
         src = (idx - r) % P               # original owner of k_cur/v_cur
         delta = q_start - src * T_loc
-        part = _flash_partial(q, k_cur, v_cur, delta, causal=causal,
-                              window=window, block_q=block_q,
+        part = _flash_partial(qh, k_cur, v_cur, delta, kv_len=T_loc,
+                              causal=causal, window=window, block_q=block_q,
                               block_k=block_k, interpret=interpret)
         state = _merge(state, part)
         if r < P - 1:
@@ -208,4 +195,4 @@ def ring_flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
 
     acc, _, l = state
     o = jnp.where(l > 0.0, acc / jnp.where(l > 0.0, l, 1.0), 0.0)
-    return o.astype(q.dtype)
+    return o.astype(q.dtype).transpose(0, 2, 1, 3)[:, :S_loc]

@@ -1,10 +1,18 @@
 """Mamba2 SSD chunked scan as a Pallas TPU kernel.
 
-Grid: one program per (batch, head).  The program walks the sequence in
-``chunk``-sized tiles, carrying the (head_dim x state) SSM state in a VMEM
-scratch buffer.  Each chunk does the quadratic intra-chunk part on the MXU
-(chunk x chunk matmul) and one state update — the same decomposition as the
-paper's SSD algorithm, re-tiled for VMEM instead of CUDA shared memory.
+Grid: (batch, head, chunk).  The wrapper lays every operand out heads-major
+((B, H, S, ·), so each block's last two dims are (chunk, width) — the
+tiling Mosaic requires) and the chunk axis is the innermost, sequential
+one: a program sees one ``chunk``-row tile of x / B / C / dt, and the
+(head_dim x state) SSM state rides across chunks in a VMEM scratch
+buffer.  Each chunk does the quadratic intra-chunk part on the MXU
+(chunk x chunk matmul) and one state update — the same decomposition as
+the paper's SSD algorithm, re-tiled for VMEM instead of CUDA shared
+memory.
+
+The per-chunk prefix sums and the row views of (chunk, 1) columns are
+masked sublane / lane reductions rather than ``cumsum`` or transposes of
+single columns, which Mosaic does not lower.
 """
 from __future__ import annotations
 
@@ -16,43 +24,45 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
-def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, o_ref, state_ref, *,
-                chunk: int, seq: int):
-    # x (S,P) dt (S,1) a (1,1) b (S,N) c (S,N) out (S,P); scratch (P,N)
-    P = x_ref.shape[-1]
-    N = b_ref.shape[-1]
-    state_ref[...] = jnp.zeros((P, N), jnp.float32)
-    a = a_ref[0].astype(jnp.float32)   # block (None, 1) -> shape (1,)
-    n_chunks = seq // chunk
+def _ssd_kernel(x_ref, dt_ref, da_ref, b_ref, c_ref, o_ref, state_ref, *,
+                chunk: int):
+    # x (Q,P) dt (Q,1) dA (Q,1) b (Q,N) c (Q,N) out (Q,P); scratch (P,N)
+    @pl.when(pl.program_id(2) == 0)
+    def _():
+        state_ref[...] = jnp.zeros(state_ref.shape, jnp.float32)
 
-    def body(ci, _):
-        sl = pl.dslice(ci * chunk, chunk)
-        x = pl.load(x_ref, (sl, slice(None))).astype(jnp.float32)   # (Q,P)
-        dt = pl.load(dt_ref, (sl, slice(None))).astype(jnp.float32)  # (Q,1)
-        bm = pl.load(b_ref, (sl, slice(None))).astype(jnp.float32)  # (Q,N)
-        cm = pl.load(c_ref, (sl, slice(None))).astype(jnp.float32)  # (Q,N)
+    x = x_ref[...].astype(jnp.float32)                     # (Q,P)
+    bm = b_ref[...].astype(jnp.float32)                    # (Q,N)
+    cm = c_ref[...].astype(jnp.float32)                    # (Q,N)
+    dt = dt_ref[...].astype(jnp.float32)                   # (Q,1)
+    da = da_ref[...].astype(jnp.float32)                   # (Q,1) negative
 
-        dA = dt[:, 0] * a                                  # (Q,) negative
-        cum = jnp.cumsum(dA)                               # inclusive
-        # intra-chunk quadratic part
-        cb = cm @ bm.T                                     # (Q,Q)
-        delta = cum[:, None] - cum[None, :]
-        iq = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
-        ik = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
-        decay = jnp.exp(jnp.where(iq >= ik, delta, -1e30))
-        m = cb * decay * dt[:, 0][None, :]
-        y = m @ x                                          # (Q,P)
-        # contribution of the carried state
-        state = state_ref[...]
-        y += jnp.exp(cum)[:, None] * (cm @ state.T)        # (Q,N)@(N,P)
-        # state update
-        decay_to_end = jnp.exp(cum[-1] - cum)              # (Q,)
-        upd = (bm * (decay_to_end * dt[:, 0])[:, None]).T @ x   # (N,Q)@(Q,P)
-        state_ref[...] = state * jnp.exp(cum[-1]) + upd.T  # (P,N)
-        pl.store(o_ref, (sl, slice(None)), y.astype(o_ref.dtype))
-        return 0
+    rows = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
+    cols = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
+    # row views: [j] = sum over rows k of the column, masked
+    cum_row = jnp.sum(jnp.where(rows <= cols, da, 0.0), axis=0,
+                      keepdims=True)                       # (1,Q) inclusive
+    dt_row = jnp.sum(jnp.where(rows == cols, dt, 0.0), axis=0, keepdims=True)
+    cum = jnp.sum(jnp.where(rows == cols, cum_row, 0.0), axis=1,
+                  keepdims=True)                           # (Q,1)
+    last = cum_row[:, chunk - 1:]                          # (1,1)
 
-    jax.lax.fori_loop(0, n_chunks, body, 0)
+    # intra-chunk quadratic part
+    cb = jax.lax.dot_general(cm, bm, (((1,), (1,)), ((), ())),
+                             preferred_element_type=jnp.float32)   # (Q,Q)
+    decay = jnp.exp(jnp.where(rows >= cols, cum - cum_row, -1e30))
+    y = jnp.dot(cb * decay * dt_row, x, preferred_element_type=jnp.float32)
+    # contribution of the carried state
+    state = state_ref[...]                                 # (P,N)
+    y += jnp.exp(cum) * jax.lax.dot_general(
+        cm, state, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32)                # (Q,N)@(N,P)
+    # state update: state * exp(cum_last) + x^T (B * decay_to_end * dt)
+    w = jnp.exp(last - cum) * dt                           # (Q,1)
+    upd = jax.lax.dot_general(x, bm * w, (((0,), (0,)), ((), ())),
+                              preferred_element_type=jnp.float32)  # (P,N)
+    state_ref[...] = state * jnp.exp(last) + upd
+    o_ref[...] = y.astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
@@ -62,25 +72,30 @@ def ssd_scan(x: jax.Array, dt: jax.Array, A: jax.Array, Bm: jax.Array,
     """x (B,S,H,P); dt (B,S,H); A (H,); Bm/Cm (B,S,H,N) -> y (B,S,H,P)."""
     B, S, H, P = x.shape
     N = Bm.shape[-1]
-    assert S % chunk == 0
+    if S % chunk:
+        raise ValueError(f"sequence length {S} is not a multiple of the "
+                         f"SSD chunk {chunk}")
 
-    grid = (B, H)
-    kernel = functools.partial(_ssd_kernel, chunk=chunk, seq=S)
-    dt4 = dt[..., None]                       # (B,S,H,1)
-    a2 = A.reshape(H, 1)
+    def heads_major(a):                       # (B,S,H,·) -> (B,H,S,·)
+        return a.transpose(0, 2, 1, 3)
 
-    return pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((None, S, None, P), lambda b, h: (b, 0, h, 0)),
-            pl.BlockSpec((None, S, None, 1), lambda b, h: (b, 0, h, 0)),
-            pl.BlockSpec((None, 1), lambda b, h: (h, 0)),
-            pl.BlockSpec((None, S, None, N), lambda b, h: (b, 0, h, 0)),
-            pl.BlockSpec((None, S, None, N), lambda b, h: (b, 0, h, 0)),
-        ],
-        out_specs=pl.BlockSpec((None, S, None, P), lambda b, h: (b, 0, h, 0)),
-        out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
+    dt4 = dt.astype(jnp.float32)[..., None]   # (B,S,H,1)
+    da4 = dt4 * A.astype(jnp.float32)[:, None]
+
+    def spec(width):
+        return pl.BlockSpec((None, None, chunk, width),
+                            lambda b, h, c: (b, h, c, 0))
+
+    y = pl.pallas_call(
+        functools.partial(_ssd_kernel, chunk=chunk),
+        grid=(B, H, S // chunk),
+        in_specs=[spec(P), spec(1), spec(1), spec(N), spec(N)],
+        out_specs=spec(P),
+        out_shape=jax.ShapeDtypeStruct((B, H, S, P), x.dtype),
         scratch_shapes=[pltpu.VMEM((P, N), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(x, dt4, a2, Bm, Cm)
+    )(heads_major(x), heads_major(dt4), heads_major(da4), heads_major(Bm),
+      heads_major(Cm))
+    return heads_major(y)

@@ -5,17 +5,15 @@ Usage:
     python -m repro.launch.dryrun --arch qwen3-8b --shape train_4k
     python -m repro.launch.dryrun --all [--multi-pod] [--out results.jsonl]
 
-The XLA_FLAGS assignment below MUST run before any other jax-importing
-module — jax locks the device count at first init.  Only this entry point
-does it; tests and benchmarks see the real (1-device) platform.
+The entry points (``main`` here, ``launch/hillclimb.py``) fake 512 host
+devices with :func:`force_host_devices` before JAX's backend starts —
+jax locks the device count at first use.  Importing this module changes
+nothing; tests and benchmarks see the real (1-device) platform.
 """
-import os
-os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "") +
-                           " --xla_force_host_platform_device_count=512").strip()
-
 import argparse
 import dataclasses
 import json
+import os
 import pathlib
 import sys
 import time
@@ -38,6 +36,14 @@ ASSIGNED = ["qwen2-72b", "qwen2.5-14b", "internvl2-26b", "kimi-k2-1t-a32b",
             "qwen3-4b", "zamba2-1.2b", "whisper-medium", "mamba2-370m",
             "arctic-480b", "qwen3-8b"]
 SHAPES = ["train_4k", "prefill_32k", "decode_32k", "long_500k"]
+
+
+def force_host_devices(n: int = 512) -> None:
+    """Fake ``n`` CPU devices for the production meshes; must run before
+    JAX's backend starts."""
+    os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "") +
+                               f" --xla_force_host_platform_device_count={n}"
+                               ).strip()
 
 
 def default_policy(cfg: ModelConfig, mode: str,
@@ -99,9 +105,6 @@ def _compile_step(cfg: ModelConfig, shape, mesh,
 def _per_device_costs(compiled) -> Dict[str, float]:
     from repro.roofline import collective_bytes_from_hlo
     cost = compiled.cost_analysis()
-    # jax <= 0.4.x returns [{...}] (one dict per partition), newer a dict
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
     colls = collective_bytes_from_hlo(compiled.as_text())
     return {
         "flops": float(cost.get("flops", 0.0)),
@@ -221,6 +224,7 @@ def main(argv=None) -> int:
     ap.add_argument("--isolate", action="store_true",
                     help="run each combo in its own subprocess")
     args = ap.parse_args(argv)
+    force_host_devices()
 
     combos = []
     meshes = [False, True] if args.both_meshes else [args.multi_pod]

@@ -3,10 +3,6 @@ variant and append the roofline row (tagged) to experiments/perf.jsonl.
 
     PYTHONPATH=src python -m repro.launch.hillclimb kimi-shmap
 """
-import os
-os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "") +
-                           " --xla_force_host_platform_device_count=512").strip()
-
 import json
 import pathlib
 import sys
@@ -46,7 +42,8 @@ VARIANTS = {
 
 
 def main():
-    from repro.launch.dryrun import run_one
+    from repro.launch.dryrun import force_host_devices, run_one
+    force_host_devices()
     name = sys.argv[1]
     spec = VARIANTS[name]
     multi = "--multi-pod" in sys.argv

@@ -14,20 +14,10 @@ import jax
 
 
 def _mk(shape, axes):
-    """Version-compat mesh constructor.
-
-    ``axis_types`` (and ``jax.sharding.AxisType``) only exist on newer jax
-    releases (>= 0.5); on older ones (e.g. 0.4.37) ``jax.make_mesh`` takes
-    just (shape, axes), and very old releases lack ``make_mesh`` entirely.
-    """
-    if hasattr(jax.sharding, "AxisType"):
-        return jax.make_mesh(
-            shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
-    if hasattr(jax, "make_mesh"):
-        return jax.make_mesh(shape, axes)
-    import numpy as np
-    devices = np.asarray(jax.devices()[: int(np.prod(shape))]).reshape(shape)
-    return jax.sharding.Mesh(devices, axes)
+    """Mesh with Auto axes: GSPMD propagates shardings and
+    ``with_sharding_constraint`` takes bare ``PartitionSpec``s."""
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

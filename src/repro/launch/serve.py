@@ -32,6 +32,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import get_config, list_archs
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.mesh import make_local_mesh
 from repro.models import init_decode_state, init_lm
 from repro.runtime import ShardPolicy, make_serve_step
@@ -177,7 +178,9 @@ def engine_config_from_args(args, cfg):
         eos_id=eos)
 
 
-def main(argv=None) -> None:
+def main(argv=None) -> List[Request]:
+    """Serve ``--requests`` synthetic prompts; returns them, each with
+    its generated tokens."""
     ap = argparse.ArgumentParser(
         prog="serve.py",
         description="Serve synthetic requests with the paged "
@@ -207,6 +210,7 @@ def main(argv=None) -> None:
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
 
+    use_compile_cache()
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = cfg.reduced()
@@ -221,6 +225,7 @@ def main(argv=None) -> None:
               eos_id=args.eos_id, seed=args.seed)
     for r in reqs[:3]:
         print(f"req {r.rid}: prompt={r.prompt} -> {r.generated[:8]}...")
+    return reqs
 
 
 if __name__ == "__main__":

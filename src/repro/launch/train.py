@@ -3,17 +3,21 @@
     PYTHONPATH=src python -m repro.launch.train --arch qwen3-4b --reduced \\
         --steps 100 --batch 8 --seq 128
 
-On this CPU container the driver runs reduced configs on the local device
-mesh; on a real pod the same entry point takes the production mesh and the
-full config (the dry-run proves those lower).
+The plan is searched for the devices JAX sees (``len(jax.devices())``
+v5e chips, batch ``--batch``) and executed on a local mesh of those
+devices: the GSPMD executor by default, the shard_map pipeline runtime
+with ``--pipeline``.  ``--reduced`` shrinks the model for CPU runs;
+without it the config keeps its published widths unless ``--layers`` /
+``--d-model`` override them.  ``main`` returns a :class:`TrainResult`.
 """
 from __future__ import annotations
 
 import argparse
-import json
+import dataclasses
 import math
 import pathlib
 import time
+from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -24,15 +28,33 @@ from repro.core import (GalvatronOptimizer, ParallelPlan, galvatron_variant,
                         tpu_v5e_pod)
 from repro.data import DataConfig, batch_specs, synthetic_lm_batches, text_corpus_batches
 from repro.checkpointing import save_train_state
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.mesh import make_local_mesh
 from repro.optim import AdamWConfig
-from repro.runtime import ShardPolicy, init_train_state, make_train_step
+from repro.runtime import init_train_state, make_train_step
+from repro.runtime.plan_bridge import model_axis_size, policy_from_plan
 
 
-def search_plan(cfg, seq_len: int, n_devices: int = 64) -> ParallelPlan:
+@dataclasses.dataclass
+class TrainResult:
+    """What a run produced: the trained state and its per-step record."""
+    plan: ParallelPlan
+    mesh_shape: Dict[str, int]
+    losses: List[float]
+    grad_norms: List[float]
+    first_step_s: float        # step 1 wall time, compilation included
+    steady_tok_per_s: float    # steps 2.. (0.0 with fewer than 2 steps)
+    params: Any = None
+    opt_state: Any = None
+
+
+def search_plan(cfg, seq_len: int, batch: int,
+                n_devices: Optional[int] = None) -> ParallelPlan:
+    """Search the plan for ``n_devices`` v5e chips (default: the devices
+    JAX sees) at global batch ``batch``."""
     specs = layerspecs_for(cfg, seq_len)
     ocfg = galvatron_variant("bmw")
-    ocfg.batch_grid = [64, 128, 256]
+    ocfg.batch_grid = [batch]
     ocfg.n_bins = 96
     ocfg.micro_candidates = 2
     ocfg.max_pp = 4
@@ -41,13 +63,43 @@ def search_plan(cfg, seq_len: int, n_devices: int = 64) -> ParallelPlan:
     # vs zero-bubble ZB-H1 (bubble for deferred weight-grad memory)
     ocfg.schedules = ("1f1b", "1f1b-interleaved", "zb-h1")
     ocfg.vpp_candidates = (2,)
-    plan = GalvatronOptimizer(specs, tpu_v5e_pod(n_devices), ocfg).optimize()
+    cluster = tpu_v5e_pod(n_devices or len(jax.devices()))
+    plan = GalvatronOptimizer(specs, cluster, ocfg).optimize()
     if plan is None:
         raise RuntimeError("no feasible plan")
     return plan
 
 
-def run_pipeline(cfg, plan: ParallelPlan, args, gen) -> None:
+def _run_steps(step_fn, state, batches, args,
+               on_step: Optional[Callable] = None) -> Tuple[Any, List, float,
+                                                            float]:
+    """Drive ``step_fn(*state, batch) -> (*state, metrics)`` for
+    ``args.steps`` steps.  Step 1 (compilation included) and the steady
+    steps after it are timed apart, each ending on the device."""
+    metrics_seen = []
+    t0 = time.perf_counter()
+    first_s = 0.0
+    for i in range(1, args.steps + 1):
+        *state, metrics = step_fn(*state, next(batches))
+        metrics_seen.append(metrics)
+        if on_step is not None:
+            on_step(i, state)
+        if i == 1:
+            jax.block_until_ready(metrics)
+            first_s = time.perf_counter() - t0
+            t0 = time.perf_counter()
+        if i % args.log_every == 0 or i == args.steps:
+            print(f"step {i:5d}  loss={float(metrics['loss']):.4f}  "
+                  f"gnorm={float(metrics['grad_norm']):.3f}")
+    jax.block_until_ready(state)
+    steady = time.perf_counter() - t0
+    tok_s = ((args.steps - 1) * args.batch * args.seq / steady
+             if args.steps > 1 else 0.0)
+    print(f"step 1 (with compile) {first_s:.2f}s; steady tok/s={tok_s:,.0f}")
+    return state, metrics_seen, first_s, tok_s
+
+
+def run_pipeline(cfg, plan: ParallelPlan, args, gen) -> TrainResult:
     """Execute the plan's searched pipeline schedule via the shard_map
     runtime, scaled down to whatever pipe degree the local devices and the
     (possibly reduced) layer count support."""
@@ -90,22 +142,24 @@ def run_pipeline(cfg, plan: ParallelPlan, args, gen) -> None:
             metrics["loss"] = loss
             return ps, opt, metrics
 
-        t0 = time.time()
-        tokens_seen = 0
-        for i in range(1, args.steps + 1):
-            b = next(gen)
-            batch = {k: jnp.asarray(v).reshape(m, args.batch // m, args.seq)
-                     for k, v in b.items()}
-            ps, opt, metrics = step(ps, opt, batch)
-            tokens_seen += args.batch * args.seq
-            if i % args.log_every == 0 or i == args.steps:
-                dt = time.time() - t0
-                print(f"step {i:5d}  loss={float(metrics['loss']):.4f}  "
-                      f"tok/s={tokens_seen/dt:,.0f}")
+        batches = ({k: jnp.asarray(v).reshape(m, args.batch // m, args.seq)
+                    for k, v in b.items()} for b in gen)
+        (ps, opt), metrics, first_s, tok_s = _run_steps(
+            step, (ps, opt), batches, args)
     print("done.")
+    return _result(plan, mesh, metrics, first_s, tok_s, ps, opt)
 
 
-def main(argv=None) -> None:
+def _result(plan, mesh, metrics, first_s, tok_s, params, opt) -> TrainResult:
+    return TrainResult(
+        plan=plan, mesh_shape=dict(mesh.shape),
+        losses=[float(x["loss"]) for x in metrics],
+        grad_norms=[float(x["grad_norm"]) for x in metrics],
+        first_step_s=first_s, steady_tok_per_s=tok_s,
+        params=params, opt_state=opt)
+
+
+def main(argv=None) -> TrainResult:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=list_archs(), default="qwen3-4b")
     ap.add_argument("--reduced", action="store_true")
@@ -140,8 +194,9 @@ def main(argv=None) -> None:
         cfg = cfg.with_(n_layers=args.layers or cfg.n_layers,
                         d_model=args.d_model or cfg.d_model)
 
+    use_compile_cache()
     # 1) the plan: loaded from a verified file, or searched fresh by the
-    #    paper's engine (for the target pod), including the
+    #    paper's engine for the local devices, including the
     #    pipeline-schedule dimension
     if args.plan:
         from repro.analysis import load_plan_file
@@ -151,7 +206,7 @@ def main(argv=None) -> None:
         print(f"loaded plan {args.plan} (verified: "
               f"{len(report.warnings())} warning(s))")
     else:
-        plan = search_plan(cfg, args.seq)
+        plan = search_plan(cfg, args.seq, args.batch)
     print("plan:", plan.summary())
     print(f"schedule: {plan.schedule} vpp={plan.vpp_degree} "
           f"m={plan.n_micro}")
@@ -168,14 +223,15 @@ def main(argv=None) -> None:
 
     # 2a) pipeline mode: execute the searched schedule itself
     if args.pipeline:
-        run_pipeline(cfg, plan, args, gen)
-        return
+        return run_pipeline(cfg, plan, args, gen)
 
     # 2b) map the plan onto the local mesh (GSPMD executor path)
-    policy = ShardPolicy.from_strategy(
-        plan.strategies[len(plan.strategies) // 2],
-        remat_segments=[s.ckpt for s in plan.strategies[:1]])
-    mesh = make_local_mesh()
+    policy = policy_from_plan(cfg, plan)
+    mesh = make_local_mesh(model=model_axis_size(plan))
+
+    def checkpoint(i, state):
+        if args.ckpt_dir and i % args.ckpt_every == 0:
+            print(f"  checkpoint -> {save_train_state(i, *state, args.ckpt_dir)}")
 
     with mesh:
         step = make_train_step(cfg, mesh, policy, batch_specs(dcfg),
@@ -184,21 +240,11 @@ def main(argv=None) -> None:
         n_params = sum(x.size for x in jax.tree_util.tree_leaves(params))
         print(f"model: {args.arch} ({n_params/1e6:.1f}M params), "
               f"mesh={dict(mesh.shape)}, policy={policy}")
-        t0 = time.time()
-        tokens_seen = 0
-        for i in range(1, args.steps + 1):
-            batch = {k: jnp.asarray(v) for k, v in next(gen).items()}
-            params, opt, metrics = step.fn(params, opt, batch)
-            tokens_seen += args.batch * args.seq
-            if i % args.log_every == 0 or i == args.steps:
-                dt = time.time() - t0
-                print(f"step {i:5d}  loss={float(metrics['loss']):.4f}  "
-                      f"gnorm={float(metrics['grad_norm']):.3f}  "
-                      f"tok/s={tokens_seen/dt:,.0f}")
-            if args.ckpt_dir and i % args.ckpt_every == 0:
-                d = save_train_state(i, params, opt, args.ckpt_dir)
-                print(f"  checkpoint -> {d}")
+        batches = ({k: jnp.asarray(v) for k, v in b.items()} for b in gen)
+        (params, opt), metrics, first_s, tok_s = _run_steps(
+            step.fn, (params, opt), batches, args, on_step=checkpoint)
     print("done.")
+    return _result(plan, mesh, metrics, first_s, tok_s, params, opt)
 
 
 if __name__ == "__main__":

@@ -69,33 +69,32 @@ def batch_sharding(axes, seq_axis=None, seq_axis_size=1, mesh=None):
         _BATCH_AXES, _SEQ_AXIS, _MESH = old, olds, oldm
 
 
-def constrain_batch(x):
-    if _BATCH_AXES is None:
-        return x
+def _constrain(x, spec_axes):
+    """Pin ``x`` to the ambient mesh.  Outside ``batch_sharding`` (no axes)
+    or without a mesh there is nothing to pin to; with both, a layout the
+    mesh cannot take is an error, not a silent no-op."""
     import jax
-    from jax.sharding import PartitionSpec as P
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    return jax.lax.with_sharding_constraint(
+        x, NamedSharding(_MESH, P(*spec_axes)))
+
+
+def constrain_batch(x):
+    if _BATCH_AXES is None or _MESH is None:
+        return x
     rest = [None] * (x.ndim - 1)
     if (_SEQ_AXIS is not None and x.ndim >= 3
             and x.shape[1] % max(1, _SEQ_AXIS[1]) == 0):
         rest[0] = _SEQ_AXIS[0]
-    try:
-        return jax.lax.with_sharding_constraint(x, P(_BATCH_AXES, *rest))
-    except (ValueError, RuntimeError):   # no mesh context
-        return x
+    return _constrain(x, (_BATCH_AXES, *rest))
 
 
 def constrain_batch_only(x):
     """Pin ONLY the leading dim to the batch axes (no sequence sharding) —
     used for tensors whose dim-1 must stay unsharded (MoE dispatch buffers)."""
-    if _BATCH_AXES is None:
+    if _BATCH_AXES is None or _MESH is None:
         return x
-    import jax
-    from jax.sharding import PartitionSpec as P
-    try:
-        return jax.lax.with_sharding_constraint(
-            x, P(_BATCH_AXES, *([None] * (x.ndim - 1))))
-    except (ValueError, RuntimeError):
-        return x
+    return _constrain(x, (_BATCH_AXES, *([None] * (x.ndim - 1))))
 
 
 def seq_sharding_active() -> bool:

@@ -184,20 +184,7 @@ def _moe_shmap(p, x: jax.Array, cfg: ModelConfig, mesh,
     k x capacity_factor smaller than the dispatch-buffer all-reduce GSPMD
     derives for the baseline mapping.
     """
-    import jax.experimental.shard_map  # noqa: F401  (older-alias safety)
     from jax.sharding import PartitionSpec as P
-    try:
-        from jax import shard_map as _sm
-
-        def _shard_map(f, in_specs, out_specs):
-            return _sm(f, mesh=mesh, in_specs=in_specs,
-                       out_specs=out_specs, check_vma=False)
-    except (ImportError, TypeError):  # pragma: no cover
-        from jax.experimental.shard_map import shard_map as _sm_old
-
-        def _shard_map(f, in_specs, out_specs):
-            return _sm_old(f, mesh=mesh, in_specs=in_specs,
-                           out_specs=out_specs, check_rep=False)
 
     G, T, d = x.shape
     E, k = cfg.n_experts, cfg.top_k
@@ -250,26 +237,11 @@ def _moe_shmap(p, x: jax.Array, cfg: ModelConfig, mesh,
     routed = {key: p[key] for key in ("router", "w_gate", "w_up", "w_down")}
     routed_specs = {key: (P("model", None, None)
                           if key != "router" else P()) for key in routed}
-    out, aux = _shard_map(local, (routed_specs, x_spec),
-                          (x_spec, P()))(routed, x)
+    out, aux = jax.shard_map(local, mesh=mesh,
+                             in_specs=(routed_specs, x_spec),
+                             out_specs=(x_spec, P()),
+                             check_vma=False)(routed, x)
     return out, aux
-
-
-def _shard_map_compat(mesh):
-    """Version-compat ``shard_map`` binder (same dance as ``_moe_shmap``)."""
-    try:
-        from jax import shard_map as _sm
-
-        def _shard_map(f, in_specs, out_specs):
-            return _sm(f, mesh=mesh, in_specs=in_specs,
-                       out_specs=out_specs, check_vma=False)
-    except (ImportError, TypeError):  # pragma: no cover
-        from jax.experimental.shard_map import shard_map as _sm_old
-
-        def _shard_map(f, in_specs, out_specs):
-            return _sm_old(f, mesh=mesh, in_specs=in_specs,
-                           out_specs=out_specs, check_rep=False)
-    return _shard_map
 
 
 def _moe_ep(p, x: jax.Array, cfg: ModelConfig, mesh,
@@ -288,7 +260,6 @@ def _moe_ep(p, x: jax.Array, cfg: ModelConfig, mesh,
     token-identical to ``dispatch="sort"`` (tests/test_moe.py certifies
     this on an 8-fake-device mesh).
     """
-    import jax.experimental.shard_map  # noqa: F401  (older-alias safety)
     from jax.sharding import PartitionSpec as P
 
     G, T, d = x.shape
@@ -349,8 +320,10 @@ def _moe_ep(p, x: jax.Array, cfg: ModelConfig, mesh,
     routed = {key: p[key] for key in ("router", "w_gate", "w_up", "w_down")}
     routed_specs = {key: (P("expert", None, None)
                           if key != "router" else P()) for key in routed}
-    out, aux = _shard_map_compat(mesh)(local, (routed_specs, x_spec),
-                                       (x_spec, P()))(routed, x)
+    out, aux = jax.shard_map(local, mesh=mesh,
+                             in_specs=(routed_specs, x_spec),
+                             out_specs=(x_spec, P()),
+                             check_vma=False)(routed, x)
     return out, aux
 
 

@@ -16,11 +16,13 @@ import dataclasses
 import re
 from typing import Dict, Optional
 
-# TPU v5e constants (task spec)
-PEAK_FLOPS = 197e12          # bf16 FLOP/s per chip
-HBM_BW = 819e9               # bytes/s per chip
-LINK_BW = 50e9               # bytes/s per ICI link (HW has multiple links;
-                             # we charge one link's worth — conservative)
+from repro.core.hardware import TPU_ICI_BW, TPU_V5E
+
+# the dry-run targets the v5e production meshes, whatever device it runs on
+PEAK_FLOPS = TPU_V5E.peak_flops     # bf16 FLOP/s per chip
+HBM_BW = TPU_V5E.hbm_bandwidth      # bytes/s per chip
+LINK_BW = TPU_ICI_BW                # bytes/s per ICI link (HW has multiple
+                                    # links; we charge one — conservative)
 
 _DTYPE_BYTES = {
     "pred": 1, "s4": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2,

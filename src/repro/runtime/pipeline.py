@@ -46,18 +46,12 @@ from repro.models.layers import cross_entropy_loss, rms_norm
 from repro.models.transformer import _BLOCK_APPLY, build_stacks
 from repro.runtime.schedules import ScheduleProgram, compile_schedule
 
-try:  # JAX >= 0.6
-    from jax import shard_map as _shard_map
 
-    def shard_map(f, mesh, in_specs, out_specs):
-        return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_vma=False)
-except (ImportError, TypeError):  # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _shard_map_old
-
-    def shard_map(f, mesh, in_specs, out_specs):
-        return _shard_map_old(f, mesh=mesh, in_specs=in_specs,
-                              out_specs=out_specs, check_rep=False)
+def shard_map(f, mesh, in_specs, out_specs):
+    """``jax.shard_map`` without the varying-manual-axes check (the stage
+    bodies mix replicated and per-device values freely)."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def stage_split_params(params, n_stages: int, n_chunks: int = 1):

@@ -3,11 +3,13 @@ Galvatron-searched ``ParallelPlan``.
 
 The search is layer-granular; the GSPMD executor applies policies per
 layer-stack *segment* (scan-over-layers keeps segments homogeneous), so the
-bridge reduces each segment's strategies to their dominant choice:
+bridge reduces the strategies to the most memory-saving choice any of them
+makes, so that no device holds more than the plan was sized for:
 
-  * TP on the `model` axis iff any layer's plan has tp > 1,
-  * ZeRO (SDP) on the batch axes iff the majority of layers use sdp > 1,
-  * remat per segment iff the majority of the segment's layers have CKPT,
+  * TP on the `model` axis iff any layer's plan has tp > 1, the axis sized
+    by the plan's largest TP degree (``model_axis_size``),
+  * ZeRO (SDP) on the batch axes iff any layer uses sdp > 1,
+  * remat per segment iff any of the segment's layers has CKPT,
   * sequence parallelism iff the modeled stash exceeds the HBM budget
     (the §Perf policy rule),
   * ring-attention SP degree copied verbatim from ``plan.sp_degree``
@@ -37,25 +39,31 @@ def _segment_bounds(cfg: ModelConfig) -> List[int]:
     return sizes
 
 
+def model_axis_size(plan: ParallelPlan) -> int:
+    """Size of the ``model`` mesh axis the executor runs ``plan`` on: its
+    largest TP degree over every layer, embedding and head included."""
+    return max(s.tp for s in plan.strategies)
+
+
 def policy_from_plan(cfg: ModelConfig, plan: ParallelPlan, *,
                      specs: Optional[Sequence[LayerSpec]] = None,
                      seq_len: int = 4096, chips: int = 256,
                      hbm_capacity: float = 16e9) -> ShardPolicy:
+    tp = model_axis_size(plan) > 1
+    zero = any(s.sdp > 1 for s in plan.strategies)
+
+    # remat follows the body layers (embed/head specs may pad the plan at
+    # either end)
     strategies = plan.strategies
-    # body layers only (embed/head specs may pad the plan at either end)
     n_body = cfg.n_layers
     if len(strategies) > n_body:
         off = (len(strategies) - n_body) // 2
         strategies = strategies[off:off + n_body]
-
-    tp = any(s.tp > 1 for s in strategies)
-    zero = sum(s.sdp > 1 for s in strategies) * 2 >= len(strategies)
-
     remat: List[bool] = []
     i = 0
     for seg in _segment_bounds(cfg):
         seg_s = strategies[i:i + seg] or strategies[-1:]
-        remat.append(sum(s.ckpt for s in seg_s) * 2 >= len(seg_s))
+        remat.append(any(s.ckpt for s in seg_s))
         i += seg
 
     seq_shard = False

@@ -1,9 +1,6 @@
 """Cost estimator: paper Table I validation, Takeaway #3, overlap slowdown."""
 import pytest
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:          # deterministic fallback sampler
-    from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import CostModel, CostModelConfig, Strategy, paper_8gpu
 from repro.core.layerspec import dense_layer, total_params

@@ -1,9 +1,6 @@
 """Search-space construction: reproduces the paper's exact counts and rules."""
 import pytest
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:          # deterministic fallback sampler
-    from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import construct_search_space, enumerate_strategies
 from repro.core.strategy import DP, SDP, TP, Strategy

@@ -9,7 +9,8 @@ from conftest import run_subprocess
 def test_executor_tp_zero_training_8dev():
     out = run_subprocess("""
 import jax, jax.numpy as jnp
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+from repro.launch.mesh import make_local_mesh
+mesh = make_local_mesh(model=2)
 from repro.configs import get_config
 from repro.runtime import ShardPolicy, make_train_step, init_train_state
 from repro.data import DataConfig, synthetic_lm_batches, batch_specs
@@ -38,7 +39,8 @@ print("OK")
 def test_pipeline_runtime_matches_reference_8dev():
     out = run_subprocess("""
 import jax, jax.numpy as jnp, numpy as np
-mesh = jax.make_mesh((4, 2), ("pipe", "data"))
+from repro.launch.mesh import make_pipeline_mesh
+mesh = make_pipeline_mesh(4, 2)
 from repro.configs import get_config
 from repro.models import init_lm, lm_loss
 from repro.runtime.pipeline import make_pipeline_loss, stage_split_params
@@ -71,7 +73,8 @@ print("OK")
 def test_moe_expert_parallel_serving_8dev():
     out = run_subprocess("""
 import jax, jax.numpy as jnp
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+from repro.launch.mesh import make_local_mesh
+mesh = make_local_mesh(model=4)
 from repro.configs import get_config
 from repro.runtime import ShardPolicy, make_serve_step
 from repro.models import init_lm, init_decode_state
@@ -112,7 +115,8 @@ def test_dryrun_entrypoint_tiny():
 def test_moe_shmap_dispatch_matches_einsum_16dev():
     out = run_subprocess("""
 import jax, jax.numpy as jnp, numpy as np
-mesh = jax.make_mesh((4, 4), ("data", "model"))
+from repro.launch.mesh import make_local_mesh
+mesh = make_local_mesh(model=4)
 from repro.configs import get_config
 from repro.models.flags import batch_sharding
 from repro.models.moe import init_moe, moe_ffn
@@ -137,7 +141,8 @@ def test_seq_shard_policy_same_loss_8dev():
     identical to the baseline (it only moves shardings)."""
     out = run_subprocess("""
 import jax, jax.numpy as jnp
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+from repro.launch.mesh import make_local_mesh
+mesh = make_local_mesh(model=4)
 from repro.configs import get_config
 from repro.runtime import ShardPolicy, make_train_step, init_train_state
 from repro.data import DataConfig, synthetic_lm_batches, batch_specs
@@ -173,7 +178,8 @@ def test_pipeline_all_schedules_match_reference_8dev():
     autodiff realizes the B/W split)."""
     out = run_subprocess("""
 import jax, jax.numpy as jnp, numpy as np
-mesh = jax.make_mesh((4, 2), ("pipe", "data"))
+from repro.launch.mesh import make_pipeline_mesh
+mesh = make_pipeline_mesh(4, 2)
 from repro.configs import get_config
 from repro.models import init_lm, lm_loss
 from repro.runtime.pipeline import make_pipeline_loss, stage_split_params
@@ -214,7 +220,8 @@ print("OK")
 def test_pipeline_1f1b_memory_schedule_matches_gpipe_8dev():
     out = run_subprocess("""
 import jax, jax.numpy as jnp, numpy as np
-mesh = jax.make_mesh((4, 2), ("pipe", "data"))
+from repro.launch.mesh import make_pipeline_mesh
+mesh = make_pipeline_mesh(4, 2)
 from repro.configs import get_config
 from repro.models import init_lm
 from repro.runtime.pipeline import make_pipeline_loss, stage_split_params

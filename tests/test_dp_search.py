@@ -4,10 +4,7 @@ import itertools
 
 import numpy as np
 import pytest
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:          # deterministic fallback sampler
-    from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import (CostModel, Strategy, dp_search_stage,
                         enumerate_strategies, paper_8gpu)

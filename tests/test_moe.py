@@ -2,7 +2,7 @@
 
 Single-process tests certify the sort path against the GShard einsum
 oracle (token-identical, including capacity drops and the shared /
-dense-residual branches); property tests on the hypothesis shim pin the
+dense-residual branches); property tests (hypothesis) pin the
 routing/capacity arithmetic; the expert-parallel (EP) path's
 token-identity claim is certified on an 8-fake-device CPU mesh in a
 subprocess (slow marker) — the PR's acceptance criterion and the runtime
@@ -21,10 +21,7 @@ from repro.models.common import ModelConfig
 from repro.models.moe import (_capacity, _route, expert_axis_usable,
                               init_moe, moe_ffn)
 
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:
-    from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 TOL = 2e-5
 
@@ -97,7 +94,7 @@ def test_capacity_overflow_drops_are_deterministic():
 
 
 # ---------------------------------------------------------------------------
-# satellite 2: routing/capacity properties (hypothesis shim)
+# satellite 2: routing/capacity properties (hypothesis)
 # ---------------------------------------------------------------------------
 
 @settings(max_examples=25)
@@ -114,7 +111,8 @@ def test_capacity_bounds(T, k, E, cf):
         assert C * E >= T * k
 
 
-@settings(max_examples=10)
+# deadline=None: the first example jit-compiles, which takes seconds
+@settings(max_examples=10, deadline=None)
 @given(st.integers(0, 1 << 16), st.integers(2, 16), st.integers(1, 3))
 def test_router_probs_normalized(seed, E, k):
     k = min(k, E)
@@ -130,7 +128,7 @@ def test_router_probs_normalized(seed, E, k):
     assert float(aux) >= 0.0               # switch aux loss is nonnegative
 
 
-@settings(max_examples=10)
+@settings(max_examples=10, deadline=None)
 @given(st.integers(0, 1 << 16))
 def test_aux_loss_invariant_under_token_permutation(seed):
     cfg = _cfg(E=4, k=2)
@@ -142,7 +140,7 @@ def test_aux_loss_invariant_under_token_permutation(seed):
     np.testing.assert_allclose(float(aux), float(aux_p), atol=1e-6)
 
 
-@settings(max_examples=10)
+@settings(max_examples=10, deadline=None)
 @given(st.integers(0, 1 << 16), st.floats(0.2, 2.0))
 def test_no_token_writes_past_capacity(seed, cf):
     """The einsum dispatch tensor — the oracle the sort path is certified

@@ -4,17 +4,13 @@ Fuzzed over random layer-time/memory vectors, stage counts, schedules and
 virtual-chunk degrees: every partition helper must return a *structurally
 valid* partition (sums to L, no empty stage), the balance degrees of Eq. 6
 must stay in [0, 1], and the greedy §IV-B2 adjustment must never shed a
-stage to empty.  Runs under real ``hypothesis`` when installed, else the
-deterministic ``_hypothesis_compat`` shim.
+stage to empty.
 """
 import itertools
 
 import numpy as np
 
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:          # deterministic fallback sampler
-    from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.core.pipeline_balance import (adjust_partition, balance_degrees,
                                          inflight_microbatches,

@@ -11,10 +11,7 @@ import pathlib
 
 import pytest
 
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:
-    from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.analysis import (DiagnosticError, detect_format_version,
                             load_plan_file, load_plan_json, verify_plan,

@@ -4,10 +4,7 @@ pure speedup — plans byte-identical to the serial oracle, telemetry
 consistent, caches auditable."""
 import pytest
 
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:          # deterministic fallback sampler
-    from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import (GalvatronOptimizer, OptimizerConfig, SEARCH_BACKENDS,
                         galvatron_variant, normalize_batch_grid, paper_8gpu)

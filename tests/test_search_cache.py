@@ -3,10 +3,7 @@ pure speedup — byte-identical plans, bit-identical cost tables."""
 import numpy as np
 import pytest
 
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:          # deterministic fallback sampler
-    from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import (CostModel, GalvatronOptimizer, enumerate_strategies,
                         galvatron_variant, paper_8gpu, paper_16gpu_low,

@@ -214,6 +214,32 @@ def test_plan_bridge_policies():
     assert not pol4.seq_shard
 
 
+def test_plan_bridge_takes_most_memory_saving_choice():
+    """A layer-wise hybrid plan runs on one policy that holds no more per
+    device than any of its layers was sized for."""
+    from repro.configs import get_config
+    from repro.runtime.plan_bridge import model_axis_size, policy_from_plan
+    cfg = get_config("qwen3-4b").with_(n_layers=8)
+    tp4, sdp4, dp4 = (Strategy(((k, 4),)) for k in ("tp", "sdp", "dp"))
+    dp4_ckpt = Strategy((("dp", 4),), ckpt=True)
+    # embedding and head tp4, a body that mostly replicates and rarely
+    # checkpoints: the majority would say no TP, no ZeRO, no remat
+    body = [sdp4] + [dp4] * 6 + [dp4_ckpt]
+    plan = ParallelPlan(n_devices=4, pp_degree=1, partition=[10],
+                        strategies=[tp4] + body + [tp4], global_batch=4,
+                        n_micro=1)
+    pol = policy_from_plan(cfg, plan)
+    assert model_axis_size(plan) == 4
+    assert pol.tp and pol.zero
+    assert pol.remat_segments == (True,)
+    flat = ParallelPlan(n_devices=4, pp_degree=1, partition=[10],
+                        strategies=[dp4] * 10, global_batch=4, n_micro=1)
+    pol = policy_from_plan(cfg, flat)
+    assert model_axis_size(flat) == 1
+    assert not pol.tp and not pol.zero
+    assert pol.remat_segments == (False,)
+
+
 def test_bf16_optimizer_state_memory_and_convergence():
     from repro.optim import AdamWConfig, adamw_init, adamw_update
     params = {"w": jnp.array([4.0, -2.0])}
