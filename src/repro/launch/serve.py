@@ -141,6 +141,12 @@ def serve_paged(cfg, requests: List[Request], ecfg, *,
               f"{summ['decode_steps']} decode steps, "
               f"{summ['prefill_chunks']} prefill chunks, "
               f"peak page occupancy {summ['page_occupancy_max']:.2f})")
+        phases = ", ".join(f"{k} {v:.3f}s/{summ['phase_n'][k]}"
+                           for k, v in sorted(summ["phase_s"].items()))
+        print(f"host: {phases}; {summ['host_syncs']} host syncs, "
+              f"{summ['compiles']} compiles ({summ['compile_s']:.2f}s), "
+              f"queue wait p50/p90 {summ['queue_wait_ms_p50']:.1f}/"
+              f"{summ['queue_wait_ms_p90']:.1f} ms")
     return metrics
 
 

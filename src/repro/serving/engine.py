@@ -24,6 +24,7 @@ import time
 from collections import deque
 from typing import Deque, List, Optional
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -105,77 +106,109 @@ class ServingEngine:
         self._slot_rm: List[Optional[RequestMetrics]] = \
             [None] * ecfg.decode_slots
 
+    # ---- host reads + page accounting ---------------------------------
+    def _host(self, x) -> np.ndarray:
+        """Read a device value on the host (one sync, counted)."""
+        self.metrics.host_syncs += 1
+        return np.asarray(x)
+
+    def _any_active(self) -> bool:
+        with self.metrics.span("page_table"):
+            return bool(self._host(self.state.active).any())
+
+    def pages_in_use(self) -> int:
+        """Pool rows held, from what the host knows: each live lane holds
+        the pages reserved for its prompt at admission, or those covering
+        its cached tokens once decode has grown past them."""
+        psz = self.ecfg.page_size
+        return sum(-(-max(len(r.prompt), len(r.prompt) + len(r.tokens) - 1)
+                     // psz) for r in self._slot_req if r is not None)
+
     # ---- admission + prefill --------------------------------------------
     def _free_slots(self) -> List[int]:
-        active = np.asarray(self.state.active)
+        with self.metrics.span("page_table"):
+            active = self._host(self.state.active)
         return [i for i in range(self.ecfg.decode_slots) if not active[i]]
 
     def _admit_batch(self, queue: Deque[ServeRequest], now: float
                      ) -> List[int]:
         """Claim slots + prompt pages for up to ``prefill_batch`` queued
         requests (arrival order); returns the admitted slot ids."""
-        admitted: List[int] = []
-        free = self._free_slots()
-        while (queue and free and len(admitted) < self.ecfg.prefill_batch):
-            req = queue[0]
-            if req.arrival_s > now:        # sorted by arrival: rest is later
-                break
-            if len(req.prompt) > self.ecfg.max_context:
-                raise ValueError(
-                    f"request {req.rid!r}: prompt length {len(req.prompt)} "
-                    f"exceeds max_context={self.ecfg.max_context}")
-            slot = free[0]
-            st, ok = self.pm.admit(self.state, slot, len(req.prompt))
-            if not bool(ok):
-                break                      # pool full — retry next round
-            self.state = st
-            queue.popleft()
-            free.pop(0)
-            self._slot_req[slot] = req
-            self._slot_rm[slot] = RequestMetrics(
-                rid=req.rid, arrival_s=now,
-                prompt_tokens=len(req.prompt),
-                deadline_ms=req.deadline_ms)
-            admitted.append(slot)
+        m = self.metrics
+        with m.span("admit") as span:
+            admitted: List[int] = []
+            waited = 0.0
+            free = self._free_slots()
+            while (queue and free
+                   and len(admitted) < self.ecfg.prefill_batch):
+                req = queue[0]
+                if req.arrival_s > now:    # sorted by arrival: rest is later
+                    break
+                if len(req.prompt) > self.ecfg.max_context:
+                    raise ValueError(
+                        f"request {req.rid!r}: prompt length "
+                        f"{len(req.prompt)} exceeds "
+                        f"max_context={self.ecfg.max_context}")
+                slot = free[0]
+                with m.span("page_table"):
+                    st, ok = self.pm.admit(self.state, slot, len(req.prompt))
+                    ok = bool(self._host(ok))
+                if not ok:
+                    break                  # pool full — retry next round
+                self.state = st
+                queue.popleft()
+                free.pop(0)
+                self._slot_req[slot] = req
+                self._slot_rm[slot] = RequestMetrics(
+                    rid=req.rid, arrival_s=req.arrival_s, admitted_s=now,
+                    prompt_tokens=len(req.prompt),
+                    deadline_ms=req.deadline_ms)
+                waited += now - req.arrival_s
+                admitted.append(slot)
+            span.set(n=len(admitted), waited_ms=waited * 1e3)
         return admitted
 
     def _prefill_admitted(self, slots: List[int], t0: float) -> None:
         """Chunked prefill for the admitted slots; records TTFT and seeds
         each lane's first generated token."""
-        ecfg, pm = self.ecfg, self.pm
+        ecfg, pm, m = self.ecfg, self.pm, self.metrics
         PB, S = ecfg.prefill_batch, ecfg.prefill_chunk
         reqs = [self._slot_req[s] for s in slots]
         plens = [len(r.prompt) for r in reqs]
-        max_len = max(plens)
-        # host-padded prompt block (PB, ceil(max_len / S) * S)
-        n_chunks = -(-max_len // S)
-        block = np.zeros((PB, n_chunks * S), np.int32)
-        for i, r in enumerate(reqs):
-            block[i, :len(r.prompt)] = r.prompt
-        rows = np.full((PB, pm.pages_per_slot), -1, np.int32)
-        rows[:len(slots)] = np.asarray(self.state.page_rows)[slots]
+        n_chunks = -(-max(plens) // S)
+        with m.span("page_table"):
+            rows = np.full((PB, pm.pages_per_slot), -1, np.int32)
+            rows[:len(slots)] = self._host(self.state.page_rows)[slots]
+            rows_j = jnp.asarray(rows)
         prompt_len = np.zeros((PB,), np.int32)
         prompt_len[:len(slots)] = plens
-        rows_j = jnp.asarray(rows)
         plen_j = jnp.asarray(prompt_len)
         for c in range(n_chunks):
             base = c * S
-            logits, self.pools = self._prefill(
-                self.params, self.pools, jnp.asarray(block[:, base:base + S]),
-                rows_j, jnp.int32(base), plen_j)
-            self.metrics.prefill_chunks += 1
-            first = np.asarray(jnp.argmax(logits, axis=-1))
-            tnow = time.perf_counter() - t0
-            for i, (slot, r) in enumerate(zip(slots, reqs)):
-                if base <= plens[i] - 1 < base + S:    # prompt ends here
-                    r.tokens.append(int(first[i]))
-                    rm = self._slot_rm[slot]
-                    rm.first_token_s = tnow
-                    rm.new_tokens = 1
+            with m.span("prefill", chunk=c, n=len(slots)):
+                # host-padded prompt block (PB, S)
+                block = np.zeros((PB, S), np.int32)
+                for i, r in enumerate(reqs):
+                    part = r.prompt[base:base + S]
+                    block[i, :len(part)] = part
+                logits, self.pools = self._prefill(
+                    self.params, self.pools, jnp.asarray(block), rows_j,
+                    jnp.int32(base), plen_j)
+                first = self._host(jnp.argmax(logits, axis=-1))
+            with m.span("bookkeep"):
+                m.prefill_chunks += 1
+                tnow = time.perf_counter() - t0
+                for i, (slot, r) in enumerate(zip(slots, reqs)):
+                    if base <= plens[i] - 1 < base + S:  # prompt ends here
+                        r.tokens.append(int(first[i]))
+                        rm = self._slot_rm[slot]
+                        rm.first_token_s = tnow
+                        rm.new_tokens = 1
         # lanes now hold their full prompt
-        self.state = self.state._replace(
-            lengths=self.state.lengths.at[jnp.asarray(slots)].set(
-                jnp.asarray(plens, jnp.int32)))
+        with m.span("page_table"):
+            self.state = self.state._replace(
+                lengths=self.state.lengths.at[jnp.asarray(slots)].set(
+                    jnp.asarray(plens, jnp.int32)))
         for slot, r in zip(slots, reqs):
             if r.max_new <= 1 or (self.ecfg.eos_id is not None
                                   and r.tokens[-1] == self.ecfg.eos_id):
@@ -183,49 +216,59 @@ class ServingEngine:
 
     # ---- decode ----------------------------------------------------------
     def _finish(self, slot: int, tnow: float) -> None:
-        req, rm = self._slot_req[slot], self._slot_rm[slot]
-        req.done = True
-        rm.new_tokens = len(req.tokens)
-        rm.finish_s = tnow
-        self.metrics.requests.append(rm)
-        self._slot_req[slot] = None
-        self._slot_rm[slot] = None
-        self.state = self.pm.free_slot(self.state, slot)
+        m = self.metrics
+        with m.span("bookkeep"):
+            req, rm = self._slot_req[slot], self._slot_rm[slot]
+            req.done = True
+            rm.new_tokens = len(req.tokens)
+            rm.finish_s = tnow
+            m.requests.append(rm)
+            self._slot_req[slot] = None
+            self._slot_rm[slot] = None
+            with m.span("page_table"):
+                self.state = self.pm.free_slot(self.state, slot)
 
     def _decode_round(self, t0: float) -> None:
         """Advance every steppable lane one token."""
-        want = self.state.active
-        st, ok = self.pm.ensure_append_capacity(self.state, want)
-        self.state = st
-        ok_np = np.asarray(ok)
+        m = self.metrics
+        with m.span("page_table"):
+            st, ok = self.pm.ensure_append_capacity(self.state,
+                                                    self.state.active)
+            self.state = st
+            ok_np = self._host(ok)
+            stuck = (not ok_np.any()
+                     and bool(self._host(self.state.active).any()))
+            lengths = jnp.where(ok, self.state.lengths, -1)
         if not ok_np.any():
-            if np.asarray(self.state.active).any():
+            if stuck:
                 raise RuntimeError(
                     "page pool exhausted: no active lane can append (grow "
                     "n_pages or lower decode_slots)")
             return
-        token = np.zeros((self.ecfg.decode_slots,), np.int32)
-        for i, r in enumerate(self._slot_req):
-            if r is not None and ok_np[i]:
-                token[i] = r.tokens[-1]
-        lengths = jnp.where(ok, self.state.lengths, -1)
-        logits, self.pools = self._decode(
-            self.params, self.pools, jnp.asarray(token),
-            self.state.page_rows, lengths)
-        nxt = np.asarray(jnp.argmax(logits, axis=-1))
-        self.state = self.pm.advance(self.state, ok)
-        self.metrics.decode_steps += 1
-        tnow = time.perf_counter() - t0
-        for i in range(self.ecfg.decode_slots):
-            if not ok_np[i]:
-                continue
-            req = self._slot_req[i]
-            req.tokens.append(int(nxt[i]))
-            finished = (len(req.tokens) >= req.max_new
-                        or (self.ecfg.eos_id is not None
-                            and int(nxt[i]) == self.ecfg.eos_id))
-            if finished:
-                self._finish(i, tnow)
+        with m.span("decode"):
+            token = np.zeros((self.ecfg.decode_slots,), np.int32)
+            for i, r in enumerate(self._slot_req):
+                if r is not None and ok_np[i]:
+                    token[i] = r.tokens[-1]
+            logits, self.pools = self._decode(
+                self.params, self.pools, jnp.asarray(token),
+                self.state.page_rows, lengths)
+            nxt = self._host(jnp.argmax(logits, axis=-1))
+        with m.span("page_table"):
+            self.state = self.pm.advance(self.state, ok)
+        with m.span("bookkeep"):
+            m.decode_steps += 1
+            tnow = time.perf_counter() - t0
+            for i in range(self.ecfg.decode_slots):
+                if not ok_np[i]:
+                    continue
+                req = self._slot_req[i]
+                req.tokens.append(int(nxt[i]))
+                finished = (len(req.tokens) >= req.max_new
+                            or (self.ecfg.eos_id is not None
+                                and int(nxt[i]) == self.ecfg.eos_id))
+                if finished:
+                    self._finish(i, tnow)
 
     # ---- top level -------------------------------------------------------
     def run(self, requests: List[ServeRequest],
@@ -234,34 +277,50 @@ class ServingEngine:
 
         Requests are admitted in arrival order as lanes and pages free up;
         ``arrival_s`` is honored against the engine's wall clock (a request
-        "arriving later" than the current elapsed time stays queued)."""
+        "arriving later" than the current elapsed time stays queued).
+        Backend compiles during the run are counted in the metrics."""
         t0 = time.perf_counter()
+        m = self.metrics
         queue: Deque[ServeRequest] = deque(
             sorted(requests, key=lambda r: r.arrival_s))
-        while queue or np.asarray(self.state.active).any():
-            now = time.perf_counter() - t0
-            slots = self._admit_batch(queue, now)
-            if slots:
-                self._prefill_admitted(slots, t0)
-            self.metrics.queue_depth.append(len(queue))
-            self.metrics.page_occupancy.append(
-                float(self.pm.occupancy(self.state)))
-            if np.asarray(self.state.active).any():
-                self._decode_round(t0)
-            elif queue:
-                if queue[0].arrival_s <= now and not slots:
-                    raise RuntimeError(
-                        f"request {queue[0].rid!r} cannot be admitted into "
-                        f"an idle engine: prompt needs "
-                        f"{-(-len(queue[0].prompt) // self.ecfg.page_size)} "
-                        f"pages but the pool has {self.ecfg.n_pages} total "
-                        "(grow n_pages)")
-                # everything queued is in the future; idle until it lands
+        jax.monitoring.register_event_duration_secs_listener(m.on_duration)
+        try:
+            while True:
+                lanes = sum(r is not None for r in self._slot_req)
+                with m.span("round", queue=len(queue), lanes=lanes):
+                    if not (queue or self._any_active()):
+                        break
+                    self._round(queue, t0)
+                if verbose:
+                    done = sum(1 for r in requests if r.done)
+                    print(f"[engine] done={done}/{len(requests)} "
+                          f"queue={len(queue)} "
+                          f"occ={self.pages_in_use() / self.ecfg.n_pages:.2f}")
+        finally:
+            jax.monitoring.unregister_event_duration_listener(m.on_duration)
+        m.wall_s = time.perf_counter() - t0
+        return m
+
+    def _round(self, queue: Deque[ServeRequest], t0: float) -> None:
+        """One iteration of the engine loop: admit and prefill what is
+        due, then one decode round (or wait for the next arrival)."""
+        m = self.metrics
+        now = time.perf_counter() - t0
+        slots = self._admit_batch(queue, now)
+        if slots:
+            self._prefill_admitted(slots, t0)
+        with m.span("bookkeep"):
+            m.sample(len(queue), self.pages_in_use() / self.ecfg.n_pages)
+        if self._any_active():
+            self._decode_round(t0)
+        elif queue:
+            if queue[0].arrival_s <= now and not slots:
+                raise RuntimeError(
+                    f"request {queue[0].rid!r} cannot be admitted into "
+                    f"an idle engine: prompt needs "
+                    f"{-(-len(queue[0].prompt) // self.ecfg.page_size)} "
+                    f"pages but the pool has {self.ecfg.n_pages} total "
+                    "(grow n_pages)")
+            # everything queued is in the future; idle until it lands
+            with m.span("wait"):
                 time.sleep(max(0.0, min(0.001, queue[0].arrival_s - now)))
-            if verbose:
-                done = sum(1 for r in requests if r.done)
-                print(f"[engine] done={done}/{len(requests)} "
-                      f"queue={len(queue)} "
-                      f"occ={float(self.pm.occupancy(self.state)):.2f}")
-        self.metrics.wall_s = time.perf_counter() - t0
-        return self.metrics

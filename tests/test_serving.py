@@ -90,12 +90,124 @@ def test_engine_arrivals_queueing_and_metrics():
     summ = metrics.summary()
     assert summ["completed"] == 5
     assert summ["queue_depth_max"] >= 1          # more requests than lanes
-    assert max(metrics.page_occupancy) <= 1.0
+    assert 0.0 < metrics.page_occupancy_max <= 1.0
+    assert metrics.page_occupancy_mean <= metrics.page_occupancy_max
     # per-request accounting: TTFT recorded before finish
     for rm in metrics.requests:
         assert rm.first_token_s is not None
         assert rm.finish_s >= rm.first_token_s
         assert rm.ttft_ms >= 0.0
+        # TTFT counts from the due time; admission comes at or after it
+        assert rm.arrival_s in (0.0, 0.01)
+        assert rm.admitted_s >= rm.arrival_s
+        assert rm.wait_ms >= 0.0
+    assert summ["queue_wait_ms_p90"] >= summ["queue_wait_ms_p50"] >= 0.0
+
+
+def _tiny_engine(ecfg):
+    from repro.launch.mesh import make_local_mesh
+    from repro.models import init_lm
+    from repro.serving import ServingEngine
+    params = jax.jit(lambda k: init_lm(k, TINY))(jax.random.PRNGKey(0))
+    return ServingEngine(TINY, params, make_local_mesh(), ecfg)
+
+
+def _host_events(trace_dir):
+    """``serve.*`` events of the profile's host threads:
+    ``(thread, start_ns, end_ns, name, stats)``."""
+    import pathlib
+    from jax.profiler import ProfileData
+    (path,) = pathlib.Path(trace_dir).glob("**/*.xplane.pb")
+    out = []
+    for plane in ProfileData.from_file(str(path)).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                out += [(line.name, e.start_ns, e.end_ns, e.name,
+                         dict(e.stats)) for e in line.events
+                        if e.name.startswith("serve.")]
+    return out
+
+
+def test_engine_spans_and_counters(tmp_path):
+    """Under the profiler every host phase appears as a ``serve.*`` span
+    on the host plane, page-table work nested in a loop iteration, and
+    admission carries its stats; a warm run compiles nothing, reads the
+    device, and its phases' own times fit in its wall time."""
+    from repro.serving import EngineConfig, ServeRequest
+    from repro.serving.metrics import ServeMetrics
+
+    ecfg = EngineConfig(page_size=4, n_pages=8, decode_slots=2,
+                        max_context=16, prefill_batch=2, prefill_chunk=4)
+    engine = _tiny_engine(ecfg)
+
+    def reqs():
+        return [ServeRequest(rid=f"r{i}", prompt=[3 + i, 5, 7, 9, 11],
+                             max_new=4, arrival_s=0.0 if i < 2 else 0.5)
+                for i in range(4)]
+
+    assert engine.run(reqs()).compiles > 0                 # cold
+    engine.metrics = ServeMetrics()
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        m = engine.run(reqs())
+    finally:
+        jax.profiler.stop_trace()
+
+    assert m.compiles == 0 and m.compile_s == 0.0
+    assert m.host_syncs > 0
+    assert sum(m.phase_s.values()) <= m.wall_s
+    phases = {"round", "admit", "prefill", "decode", "page_table",
+              "bookkeep", "wait"}
+    assert set(m.phase_s) == set(m.phase_n) == phases
+    assert m.phase_n["prefill"] == m.prefill_chunks
+    assert m.phase_n["decode"] == m.decode_steps
+    summ = m.summary()
+    assert summ["compiles"] == 0 and summ["host_syncs"] == m.host_syncs
+    assert summ["phase_s"] == m.phase_s
+
+    ev = _host_events(tmp_path)
+    assert {n for *_, n, _ in ev} == {"serve." + p for p in phases}
+    rounds = [(t, s, e) for t, s, e, n, _ in ev if n == "serve.round"]
+    for t, s, e, n, _ in ev:
+        if n == "serve.page_table":
+            assert any(t == rt and rs <= s and e <= re
+                       for rt, rs, re in rounds), (s, e)
+    admits = [st for *_, n, st in ev if n == "serve.admit"]
+    assert sum(st["n"] for st in admits) == 4
+    assert all("waited_ms" in st and st["waited_ms"] >= 0 for st in admits)
+    assert all({"queue", "lanes"} <= set(st) for *_, n, st in ev
+               if n == "serve.round")
+
+
+def test_host_occupancy_matches_page_table():
+    """The page occupancy the engine counts on the host equals the page
+    table's own at every loop iteration of a run whose lanes cross page
+    boundaries and whose pages are reused by later requests."""
+    from repro.serving import EngineConfig, ServeRequest
+
+    ecfg = EngineConfig(page_size=4, n_pages=8, decode_slots=2,
+                        max_context=16, prefill_batch=2, prefill_chunk=4)
+    engine = _tiny_engine(ecfg)
+    seen = []
+    sample = engine.metrics.sample
+
+    def checked(queue_depth, occupancy):
+        seen.append((occupancy, float(engine.pm.occupancy(engine.state))))
+        sample(queue_depth, occupancy)
+
+    engine.metrics.sample = checked
+    reqs = [ServeRequest(rid=f"r{i}", prompt=[3 + i, 5, 7], max_new=8)
+            for i in range(5)]                  # 3 pages each, 15 > 8 rows
+    m = engine.run(reqs)
+    assert all(r.done and len(r.tokens) == 8 for r in reqs)
+    assert len(seen) == m.samples > 5
+    for host, table in seen:
+        assert host == pytest.approx(table)
+    assert max(h for h, _ in seen) == pytest.approx(6 / 8)
+    assert m.page_occupancy_max == pytest.approx(6 / 8)
+    assert engine.pages_in_use() == 0
 
 
 def test_engine_rejects_oversized_prompt_and_unsupported_arch():
