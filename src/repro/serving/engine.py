@@ -16,6 +16,15 @@ free decode lanes — the decode batch never waits for a prompt to be fed
 token-by-token.  Slots are recycled as requests finish (EOS / max_new) and
 their pages return to the pool, so total KV memory is bounded by pages
 actually cached, not ``lanes * max_context``.
+
+The page table (``page_table.py``) lives on the host as numpy arrays: every
+allocation, the lanes' lengths and which lanes are active are decided there
+with no device program and no device read.  Each step call receives what
+it needs of the table as small host-to-device copies (decode: the tokens,
+``page_rows`` and the lengths, -1 for lanes that do not step; prefill: the
+admitted lanes' rows and prompt lengths).  The only device-to-host reads
+are the ``argmax`` of each step's logits, one per decode round and one per
+prefill chunk.
 """
 from __future__ import annotations
 
@@ -114,7 +123,7 @@ class ServingEngine:
 
     def _any_active(self) -> bool:
         with self.metrics.span("page_table"):
-            return bool(self._host(self.state.active).any())
+            return bool(self.state.active.any())
 
     def pages_in_use(self) -> int:
         """Pool rows held, from what the host knows: each live lane holds
@@ -127,8 +136,7 @@ class ServingEngine:
     # ---- admission + prefill --------------------------------------------
     def _free_slots(self) -> List[int]:
         with self.metrics.span("page_table"):
-            active = self._host(self.state.active)
-        return [i for i in range(self.ecfg.decode_slots) if not active[i]]
+            return np.flatnonzero(~self.state.active).tolist()
 
     def _admit_batch(self, queue: Deque[ServeRequest], now: float
                      ) -> List[int]:
@@ -152,7 +160,6 @@ class ServingEngine:
                 slot = free[0]
                 with m.span("page_table"):
                     st, ok = self.pm.admit(self.state, slot, len(req.prompt))
-                    ok = bool(self._host(ok))
                 if not ok:
                     break                  # pool full — retry next round
                 self.state = st
@@ -178,8 +185,8 @@ class ServingEngine:
         n_chunks = -(-max(plens) // S)
         with m.span("page_table"):
             rows = np.full((PB, pm.pages_per_slot), -1, np.int32)
-            rows[:len(slots)] = self._host(self.state.page_rows)[slots]
-            rows_j = jnp.asarray(rows)
+            rows[:len(slots)] = self.state.page_rows[slots]
+        rows_j = jnp.asarray(rows)
         prompt_len = np.zeros((PB,), np.int32)
         prompt_len[:len(slots)] = plens
         plen_j = jnp.asarray(prompt_len)
@@ -206,9 +213,9 @@ class ServingEngine:
                         rm.new_tokens = 1
         # lanes now hold their full prompt
         with m.span("page_table"):
-            self.state = self.state._replace(
-                lengths=self.state.lengths.at[jnp.asarray(slots)].set(
-                    jnp.asarray(plens, jnp.int32)))
+            lengths = self.state.lengths.copy()
+            lengths[slots] = plens
+            self.state = self.state._replace(lengths=lengths)
         for slot, r in zip(slots, reqs):
             if r.max_new <= 1 or (self.ecfg.eos_id is not None
                                   and r.tokens[-1] == self.ecfg.eos_id):
@@ -232,14 +239,11 @@ class ServingEngine:
         """Advance every steppable lane one token."""
         m = self.metrics
         with m.span("page_table"):
-            st, ok = self.pm.ensure_append_capacity(self.state,
-                                                    self.state.active)
-            self.state = st
-            ok_np = self._host(ok)
-            stuck = (not ok_np.any()
-                     and bool(self._host(self.state.active).any()))
-            lengths = jnp.where(ok, self.state.lengths, -1)
-        if not ok_np.any():
+            self.state, ok = self.pm.ensure_append_capacity(
+                self.state, self.state.active)
+            stuck = not ok.any() and bool(self.state.active.any())
+            lengths = np.where(ok, self.state.lengths, np.int32(-1))
+        if not ok.any():
             if stuck:
                 raise RuntimeError(
                     "page pool exhausted: no active lane can append (grow "
@@ -248,11 +252,11 @@ class ServingEngine:
         with m.span("decode"):
             token = np.zeros((self.ecfg.decode_slots,), np.int32)
             for i, r in enumerate(self._slot_req):
-                if r is not None and ok_np[i]:
+                if r is not None and ok[i]:
                     token[i] = r.tokens[-1]
             logits, self.pools = self._decode(
                 self.params, self.pools, jnp.asarray(token),
-                self.state.page_rows, lengths)
+                jnp.asarray(self.state.page_rows), jnp.asarray(lengths))
             nxt = self._host(jnp.argmax(logits, axis=-1))
         with m.span("page_table"):
             self.state = self.pm.advance(self.state, ok)
@@ -260,7 +264,7 @@ class ServingEngine:
             m.decode_steps += 1
             tnow = time.perf_counter() - t0
             for i in range(self.ecfg.decode_slots):
-                if not ok_np[i]:
+                if not ok[i]:
                     continue
                 req = self._slot_req[i]
                 req.tokens.append(int(nxt[i]))

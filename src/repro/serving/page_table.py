@@ -1,41 +1,55 @@
-"""Paged KV-cache bookkeeping: a functional page table over dense int32
-arrays, usable eagerly from the host engine or traced under ``jax.jit``.
+"""Paged KV-cache bookkeeping: a functional page table over small numpy
+arrays that live on the host.
 
 The design follows the vLLM / maxtext ``page_manager`` idiom: one shared
 pool of fixed-size pages per layer holds every lane's K/V, and a *single*
 page table (shared by all layers — each layer indexes its own pool with the
 same rows) maps (slot, logical page) -> pool row.  All state lives in
-:class:`PageState`, a pytree of dense arrays updated functionally; the
-static geometry lives in :class:`PageManager`.  There is no Python-object
-free list: allocation is rank-matching with ``cumsum`` over boolean masks,
-and every scatter routes invalid positions out of bounds where
-``mode="drop"`` discards them — the same trick the paged attention kernels
-use for inactive lanes.
+:class:`PageState`, a tuple of dense numpy arrays updated functionally; the
+static geometry lives in :class:`PageManager`.  Allocation is rank
+matching: the r-th lane (or logical page) that needs a page gets the r-th
+free pool row, in row order.
+
+The table stays on the host, as vLLM's block manager and JetStream keep
+their block tables: it is a few KB, the host decides every allocation, and
+nothing on the device reads it except as an argument of the jitted prefill
+and decode steps.  Each op is a handful of numpy calls (microseconds) with
+no device program and no device-to-host read; the engine copies
+``page_rows`` and the lanes' lengths to the device once per step call.
+Every op accepts any array-like state or mask (``np.asarray`` of it), so
+``jax`` arrays work as inputs too.
 """
 from __future__ import annotations
 
 import dataclasses
 from typing import NamedTuple, Tuple
 
-import jax
-import jax.numpy as jnp
+import numpy as np
 
 
 class PageState(NamedTuple):
-    """Dense-array page table (a jax pytree).
+    """Dense-array page table (numpy, on the host).
 
-    ``page_owner`` (n_pages,) — slot owning each pool row, -1 = free.
-    ``page_rows`` (n_slots, pages_per_slot) — pool row backing each lane's
-    logical page, -1 = unassigned.
-    ``lengths`` (n_slots,) — tokens currently cached per lane (= the write
-    position of the next token).
+    ``page_owner`` (n_pages,) int32 — slot owning each pool row, -1 = free.
+    ``page_rows`` (n_slots, pages_per_slot) int32 — pool row backing each
+    lane's logical page, -1 = unassigned.
+    ``lengths`` (n_slots,) int32 — tokens currently cached per lane (= the
+    write position of the next token).
     ``active`` (n_slots,) bool — lane holds a live request.
     """
 
-    page_owner: jax.Array
-    page_rows: jax.Array
-    lengths: jax.Array
-    active: jax.Array
+    page_owner: np.ndarray
+    page_rows: np.ndarray
+    lengths: np.ndarray
+    active: np.ndarray
+
+
+def _as_numpy(st: PageState) -> PageState:
+    """``st`` with numpy fields (no copy when they already are)."""
+    return PageState(np.asarray(st.page_owner, np.int32),
+                     np.asarray(st.page_rows, np.int32),
+                     np.asarray(st.lengths, np.int32),
+                     np.asarray(st.active, bool))
 
 
 @dataclasses.dataclass(frozen=True)
@@ -45,7 +59,7 @@ class PageManager:
     ``n_pages`` pool rows of ``page_size`` tokens are shared by ``n_slots``
     decode lanes, each addressing at most ``pages_per_slot`` logical pages
     (so per-lane max context = pages_per_slot * page_size).  Methods take
-    and return :class:`PageState`; none mutate.
+    and return :class:`PageState`; none mutate their input.
     """
 
     n_pages: int
@@ -64,111 +78,112 @@ class PageManager:
 
     def init(self) -> PageState:
         return PageState(
-            page_owner=jnp.full((self.n_pages,), -1, jnp.int32),
-            page_rows=jnp.full((self.n_slots, self.pages_per_slot), -1,
-                               jnp.int32),
-            lengths=jnp.zeros((self.n_slots,), jnp.int32),
-            active=jnp.zeros((self.n_slots,), bool),
+            page_owner=np.full((self.n_pages,), -1, np.int32),
+            page_rows=np.full((self.n_slots, self.pages_per_slot), -1,
+                              np.int32),
+            lengths=np.zeros((self.n_slots,), np.int32),
+            active=np.zeros((self.n_slots,), bool),
         )
 
     # ---- queries ---------------------------------------------------------
-    def pages_needed(self, n_tokens) -> jax.Array:
+    def pages_needed(self, n_tokens) -> np.ndarray:
         """Pages required to hold ``n_tokens`` (ceil division)."""
-        n = jnp.asarray(n_tokens, jnp.int32)
+        n = np.asarray(n_tokens, np.int32)
         return (n + self.page_size - 1) // self.page_size
 
-    def free_pages(self, st: PageState) -> jax.Array:
-        return jnp.sum(st.page_owner < 0).astype(jnp.int32)
+    def free_pages(self, st: PageState) -> np.int32:
+        return np.int32(np.count_nonzero(np.asarray(st.page_owner) < 0))
 
-    def used_pages(self, st: PageState) -> jax.Array:
-        return jnp.sum(st.page_owner >= 0).astype(jnp.int32)
+    def used_pages(self, st: PageState) -> np.int32:
+        return np.int32(np.count_nonzero(np.asarray(st.page_owner) >= 0))
 
-    def occupancy(self, st: PageState) -> jax.Array:
+    def occupancy(self, st: PageState) -> np.float64:
         return self.used_pages(st) / self.n_pages
 
     # ---- allocation ------------------------------------------------------
     def reserve(self, st: PageState, slot, n_need
-                ) -> Tuple[PageState, jax.Array]:
+                ) -> Tuple[PageState, np.bool_]:
         """Assign the first ``n_need`` free pool rows to ``slot``'s next
         unassigned logical pages.  Returns ``(new_state, ok)``; on failure
         (not enough free rows, or the slot would exceed pages_per_slot)
         the state is returned unchanged and ``ok`` is False."""
-        slot = jnp.asarray(slot, jnp.int32)
-        n_need = jnp.asarray(n_need, jnp.int32)
-        free = st.page_owner < 0                             # (n_pages,)
-        rank = jnp.cumsum(free) - 1                          # rank among free
-        chosen = free & (rank < n_need)
-        cur = jnp.sum(st.page_rows[slot] >= 0).astype(jnp.int32)
-        ok = ((jnp.sum(free) >= n_need)
-              & (cur + n_need <= self.pages_per_slot))
-        # logical index each chosen row lands in; non-chosen rows route OOB
-        logical = jnp.where(chosen & ok, cur + rank, self.pages_per_slot)
-        new_rows = st.page_rows.at[slot, logical].set(
-            jnp.arange(self.n_pages, dtype=jnp.int32), mode="drop")
-        new_owner = jnp.where(chosen & ok, slot, st.page_owner)
-        return PageState(new_owner, new_rows, st.lengths, st.active), ok
+        st = _as_numpy(st)
+        slot, n_need = int(slot), int(n_need)
+        free = np.flatnonzero(st.page_owner < 0)             # in row order
+        cur = int(np.count_nonzero(st.page_rows[slot] >= 0))
+        ok = np.bool_(len(free) >= n_need
+                      and cur + n_need <= self.pages_per_slot)
+        if not ok or n_need <= 0:
+            return st, ok
+        chosen = free[:n_need]
+        rows = st.page_rows.copy()
+        rows[slot, cur:cur + n_need] = chosen
+        owner = st.page_owner.copy()
+        owner[chosen] = slot
+        return PageState(owner, rows, st.lengths, st.active), ok
 
     def admit(self, st: PageState, slot, prompt_len
-              ) -> Tuple[PageState, jax.Array]:
+              ) -> Tuple[PageState, np.bool_]:
         """Claim ``slot`` for a new request and reserve pages covering its
         ``prompt_len`` prompt tokens.  The lane starts at length 0 (prefill
         fills it); decode-time pages come from :meth:`ensure_append_capacity`.
+        On failure nothing changes (full rollback) and ``ok`` is False.
         """
-        slot = jnp.asarray(slot, jnp.int32)
+        slot = int(slot)
         st2, ok = self.reserve(st, slot, self.pages_needed(prompt_len))
-        new_active = st2.active.at[slot].set(ok)
-        new_lengths = st2.lengths.at[slot].set(0)
-        st3 = PageState(st2.page_owner, st2.page_rows, new_lengths,
-                        new_active)
-        return jax.tree.map(lambda a, b: jnp.where(ok, a, b), st3, st), ok
+        if not ok:
+            return st2, ok
+        lengths, active = st2.lengths.copy(), st2.active.copy()
+        lengths[slot] = 0
+        active[slot] = True
+        return st2._replace(lengths=lengths, active=active), ok
 
     def free_slot(self, st: PageState, slot) -> PageState:
         """Release every page owned by ``slot`` and deactivate the lane."""
-        slot = jnp.asarray(slot, jnp.int32)
-        new_owner = jnp.where(st.page_owner == slot, -1, st.page_owner)
-        new_rows = st.page_rows.at[slot].set(-1)
-        return PageState(new_owner, new_rows,
-                         st.lengths.at[slot].set(0),
-                         st.active.at[slot].set(False))
+        st = _as_numpy(st)
+        slot = int(slot)
+        owner = np.where(st.page_owner == slot, np.int32(-1), st.page_owner)
+        rows, lengths, active = (st.page_rows.copy(), st.lengths.copy(),
+                                 st.active.copy())
+        rows[slot] = -1
+        lengths[slot] = 0
+        active[slot] = False
+        return PageState(owner, rows, lengths, active)
 
-    def ensure_append_capacity(self, st: PageState, want: jax.Array
-                               ) -> Tuple[PageState, jax.Array]:
+    def ensure_append_capacity(self, st: PageState, want
+                               ) -> Tuple[PageState, np.ndarray]:
         """Guarantee each lane in ``want`` (n_slots, bool) has a page
         assigned for its next write position ``lengths[i]``.
 
-        Vectorized multi-lane allocation: lanes missing a page are ranked
-        by ``cumsum``, free pool rows are ranked the same way, and rank r
-        matches rank r.  Returns ``(new_state, ok)`` with ``ok`` (n_slots,)
-        False for lanes that could not get a page this round (pool
-        exhausted or lane at pages_per_slot) — the engine skips those lanes
-        for one step and retries after other requests release pages."""
-        want = want & st.active
+        Multi-lane allocation: the lanes missing a page, in lane order, are
+        matched with the free pool rows, in row order — rank r gets rank r.
+        Returns ``(new_state, ok)`` with ``ok`` (n_slots,) False for lanes
+        that could not get a page this round (pool exhausted or lane at
+        pages_per_slot) — the engine skips those lanes for one step and
+        retries after other requests release pages."""
+        st = _as_numpy(st)
+        want = np.asarray(want, bool) & st.active
         li = st.lengths // self.page_size                    # logical page
-        li_c = jnp.clip(li, 0, self.pages_per_slot - 1)
-        have = jnp.take_along_axis(st.page_rows, li_c[:, None],
-                                   axis=1)[:, 0] >= 0
+        li_c = np.clip(li, 0, self.pages_per_slot - 1)
+        lanes = np.arange(self.n_slots)
+        have = st.page_rows[lanes, li_c] >= 0
         fits = li < self.pages_per_slot
-        need = want & fits & ~have
-        lane_rank = jnp.cumsum(need) - 1                     # (n_slots,)
-        free = st.page_owner < 0
-        free_rank = jnp.where(free, jnp.cumsum(free) - 1, self.n_slots)
-        # page_of_rank[r] = r-th free pool row (sentinel n_pages if none)
-        page_of_rank = jnp.full((self.n_slots,), self.n_pages,
-                                jnp.int32).at[free_rank].set(
-            jnp.arange(self.n_pages, dtype=jnp.int32), mode="drop")
-        got = page_of_rank[jnp.clip(lane_rank, 0, self.n_slots - 1)]
-        granted = need & (got < self.n_pages)
-        slot_ids = jnp.arange(self.n_slots, dtype=jnp.int32)
-        new_rows = st.page_rows.at[
-            jnp.where(granted, slot_ids, self.n_slots), li_c].set(
-            got, mode="drop")
-        new_owner = st.page_owner.at[
-            jnp.where(granted, got, self.n_pages)].set(
-            slot_ids, mode="drop")
-        ok = want & fits & (have | granted)
-        return PageState(new_owner, new_rows, st.lengths, st.active), ok
+        need = np.flatnonzero(want & fits & ~have)
+        free = np.flatnonzero(st.page_owner < 0)
+        k = min(len(need), len(free))
+        granted = np.zeros((self.n_slots,), bool)
+        if k:
+            lane, row = need[:k], free[:k]
+            granted[lane] = True
+            rows = st.page_rows.copy()
+            rows[lane, li_c[lane]] = row
+            owner = st.page_owner.copy()
+            owner[row] = lane
+            st = st._replace(page_owner=owner, page_rows=rows)
+        return st, want & fits & (have | granted)
 
-    def advance(self, st: PageState, stepped: jax.Array) -> PageState:
+    def advance(self, st: PageState, stepped) -> PageState:
         """Bump ``lengths`` for lanes that wrote a token this step."""
+        st = _as_numpy(st)
         return st._replace(
-            lengths=st.lengths + stepped.astype(jnp.int32))
+            lengths=st.lengths + np.asarray(stepped).astype(np.int32))

@@ -210,6 +210,52 @@ def test_host_occupancy_matches_page_table():
     assert engine.pages_in_use() == 0
 
 
+def test_engine_reads_the_device_only_for_argmax():
+    """The page table lives on the host: over a run whose lanes cross page
+    boundaries and whose pages are reused by later requests, the engine's
+    only device-to-host reads are one ``argmax`` per decode round and one
+    per prefill chunk."""
+    from repro.serving import EngineConfig, ServeRequest
+
+    ecfg = EngineConfig(page_size=4, n_pages=8, decode_slots=2,
+                        max_context=16, prefill_batch=2, prefill_chunk=4)
+    engine = _tiny_engine(ecfg)
+    pm, granted = engine.pm, {"admit": [], "append": []}
+
+    class Recording:
+        """Delegates to the engine's page manager; records rows granted."""
+
+        def __getattr__(self, name):
+            return getattr(pm, name)
+
+        def _record(self, kind, before, after):
+            new = (after.page_rows >= 0) & (before.page_rows < 0)
+            granted[kind] += after.page_rows[new].tolist()
+
+        def admit(self, st, slot, prompt_len):
+            st2, ok = pm.admit(st, slot, prompt_len)
+            self._record("admit", st, st2)
+            return st2, ok
+
+        def ensure_append_capacity(self, st, want):
+            st2, ok = pm.ensure_append_capacity(st, want)
+            self._record("append", st, st2)
+            return st2, ok
+
+    engine.pm = Recording()
+    reqs = [ServeRequest(rid=f"r{i}", prompt=[3 + i, 5, 7, 9, 11][:3 + i % 3],
+                         max_new=8) for i in range(5)]   # 3-5 prompt tokens
+    m = engine.run(reqs)
+    assert all(r.done and len(r.tokens) == 8 for r in reqs)
+    assert m.prefill_chunks > 3                 # 5-token prompts: 2 chunks
+    assert m.host_syncs == m.decode_steps + m.prefill_chunks
+    assert granted["append"]                    # lanes crossed a page edge
+    all_rows = granted["admit"] + granted["append"]
+    assert len(all_rows) > ecfg.n_pages         # rows went back and out again
+    assert set(all_rows) <= set(range(ecfg.n_pages))
+    assert int(pm.used_pages(engine.state)) == 0
+
+
 def test_engine_rejects_oversized_prompt_and_unsupported_arch():
     from repro.launch.mesh import make_local_mesh
     from repro.models import init_lm
