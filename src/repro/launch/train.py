@@ -32,7 +32,8 @@ from repro.launch.compile_cache import use_compile_cache
 from repro.launch.mesh import make_local_mesh
 from repro.optim import AdamWConfig
 from repro.runtime import init_train_state, make_train_step
-from repro.runtime.plan_bridge import model_axis_size, policy_from_plan
+from repro.runtime.plan_bridge import (execution_line, model_axis_size,
+                                       policy_from_plan)
 
 
 @dataclasses.dataclass
@@ -228,6 +229,7 @@ def main(argv=None) -> TrainResult:
     # 2b) map the plan onto the local mesh (GSPMD executor path)
     policy = policy_from_plan(cfg, plan)
     mesh = make_local_mesh(model=model_axis_size(plan))
+    print(execution_line(plan, policy, mesh.shape))
 
     def checkpoint(i, state):
         if args.ckpt_dir and i % args.ckpt_every == 0:

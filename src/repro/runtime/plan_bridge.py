@@ -19,10 +19,14 @@ makes, so that no device holds more than the plan was sized for:
   * expert-parallel degree copied verbatim from ``plan.ep_degree``
     (the searched axis, format v5) — expert weights shard over the mesh's
     ``expert`` axis and MoE dispatch runs the all-to-all path
-    (models/moe.py::_moe_ep).
+    (models/moe.py::_moe_ep),
+  * one pipeline stage whatever ``plan.pp_degree`` is (the shard_map
+    pipeline runtime, ``pipeline_loss_from_plan``, is the other path);
+    ``execution_line`` names what the executor drops of the plan.
 """
 from __future__ import annotations
 
+from collections import Counter
 from typing import List, Optional, Sequence
 
 from repro.core.layerspec import LayerSpec
@@ -78,6 +82,32 @@ def policy_from_plan(cfg: ModelConfig, plan: ParallelPlan, *,
                        seq_shard=seq_shard, sp_degree=plan.sp_degree,
                        ep_degree=ep,
                        expert_axis="expert" if ep > 1 else "model")
+
+
+def execution_line(plan: ParallelPlan, policy: ShardPolicy,
+                   mesh_shape) -> str:
+    """One line: the searched plan (pipeline degree, schedule,
+    micro-batches, per-layer TP/SDP/CKPT counts) beside what the GSPMD
+    executor runs of it (policy and mesh), naming what it drops.
+    ``policy_from_plan`` keeps one stage whatever ``plan.pp_degree`` is,
+    and one TP, ZeRO and remat choice for every layer."""
+    def counts(name: str) -> str:
+        n = Counter(getattr(s, name) for s in plan.strategies)
+        return ", ".join(f"{name}{k} x{v}" for k, v in sorted(n.items()))
+
+    sched = plan.schedule + (f"(V={plan.vpp_degree})"
+                             if plan.vpp_degree > 1 else "")
+    dropped = [f"pp{plan.pp_degree} {sched} m={plan.n_micro}"
+               ] if plan.pp_degree > 1 else []
+    if len({(s.tp, s.sdp, s.ckpt) for s in plan.strategies}) > 1:
+        dropped.append("per-layer tp/sdp/ckpt")
+    return (f"searched: pp{plan.pp_degree} {sched} m={plan.n_micro}; "
+            f"{len(plan.strategies)} layer specs: {counts('tp')}; "
+            f"{counts('sdp')}; ckpt x{sum(s.ckpt for s in plan.strategies)}"
+            f" | executed: one GSPMD stage on mesh {dict(mesh_shape)}, "
+            f"tp={policy.tp} zero={policy.zero} "
+            f"remat={any(policy.remat_segments or ())} | dropped: "
+            + (", ".join(dropped) or "nothing"))
 
 
 def schedule_program_from_plan(plan: ParallelPlan, *,
