@@ -24,7 +24,7 @@ def _interpret() -> bool:
 
 def flash_attention(q, k, v, *, causal: bool = True,
                     window: Optional[int] = None,
-                    block_q: int = 128, block_k: int = 128):
+                    block_q: int = _fa.BLOCK_Q, block_k: int = _fa.BLOCK_K):
     return _fa.flash_attention(q, k, v, causal=causal, window=window,
                                block_q=block_q, block_k=block_k,
                                interpret=_interpret())

@@ -32,8 +32,8 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.kernels.flash_attention import (DIM_SEMANTICS, NEG_INF,
                                            block_sizes, heads_major,
                                            init_softmax_state,
-                                           online_softmax_step,
-                                           _validate_attn_shapes)
+                                           online_softmax_step, when_live,
+                                           _positions, _validate_attn_shapes)
 
 
 def _partial_kernel(delta_ref, q_ref, k_ref, v_ref, acc_ref, m_ref, l_ref,
@@ -56,23 +56,14 @@ def _partial_kernel(delta_ref, q_ref, k_ref, v_ref, acc_ref, m_ref, l_ref,
     # q/k positions on the visiting panel's local axis: k_global <=
     # q_global is exactly k_local <= q_local + delta
     q_lo, k_lo = iq * block_q + delta, ik * block_k
-    live = []
-    if causal:          # delta is dynamic, so the tile skip is too
-        live.append(k_lo <= q_lo + block_q - 1)
-    if window is not None:
-        live.append(k_lo + block_k - 1 > q_lo - window)
 
-    def step():
-        q_pos = q_lo + jax.lax.broadcasted_iota(jnp.int32, (block_q, 1), 0)
-        k_pos = k_lo + jax.lax.broadcasted_iota(jnp.int32, (1, block_k), 1)
+    def step(**masks):       # delta is dynamic, so the tile skip is too
+        q_pos, k_pos = _positions(q_lo, k_lo, block_q, block_k)
         online_softmax_step(q_ref, k_ref, v_ref, acc_s, m_s, l_s,
-                            q_pos=q_pos, k_pos=k_pos, scale=scale,
-                            causal=causal, window=window, kv_len=kv_len)
+                            q_pos=q_pos, k_pos=k_pos, scale=scale, **masks)
 
-    if live:
-        pl.when(functools.reduce(jnp.logical_and, live))(step)
-    else:
-        step()
+    when_live(step, q_lo, k_lo, block_q=block_q, block_k=block_k,
+              causal=causal, window=window, kv_len=kv_len)
 
     @pl.when(ik == pl.num_programs(3) - 1)
     def _():

@@ -30,6 +30,7 @@ from repro.data import DataConfig, batch_specs, synthetic_lm_batches, text_corpu
 from repro.checkpointing import save_train_state
 from repro.launch.compile_cache import use_compile_cache
 from repro.launch.mesh import make_local_mesh
+from repro.models.attention import recording_attention
 from repro.optim import AdamWConfig
 from repro.runtime import init_train_state, make_train_step
 from repro.runtime.plan_bridge import (execution_line, model_axis_size,
@@ -229,7 +230,6 @@ def main(argv=None) -> TrainResult:
     # 2b) map the plan onto the local mesh (GSPMD executor path)
     policy = policy_from_plan(cfg, plan)
     mesh = make_local_mesh(model=model_axis_size(plan))
-    print(execution_line(plan, policy, mesh.shape))
 
     def checkpoint(i, state):
         if args.ckpt_dir and i % args.ckpt_every == 0:
@@ -238,6 +238,9 @@ def main(argv=None) -> TrainResult:
     with mesh:
         step = make_train_step(cfg, mesh, policy, batch_specs(dcfg),
                                AdamWConfig(lr=args.lr))
+        with recording_attention() as attn:
+            step.fn.trace(*step.abstract_args)
+        print(execution_line(plan, policy, mesh.shape, attn))
         params, opt = init_train_state(cfg, mesh, policy)
         n_params = sum(x.size for x in jax.tree_util.tree_leaves(params))
         print(f"model: {args.arch} ({n_params/1e6:.1f}M params), "

@@ -3,16 +3,37 @@ sliding-window masking, KV-cache decode, and a pluggable inner kernel
 (pure-jnp reference here; Pallas flash kernel in repro.kernels)."""
 from __future__ import annotations
 
-from typing import Any, Dict, Optional, Tuple
+import contextlib
+import math
+from typing import Any, Dict, Optional, Set, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from .common import ModelConfig
-from .flags import scan_unroll
+from .flags import (constrain_batch_only, current_batch_axes, current_mesh,
+                    scan_unroll, seq_sharding_active, unroll_scans)
 from .layers import apply_rope, init_dense, rms_norm
 
 NEG_INF = -1e30
+
+# set by ``recording_attention``: the inner kernels ``attention`` resolved
+# to while tracing under it
+_RESOLVED: Optional[Set[str]] = None
+
+
+@contextlib.contextmanager
+def recording_attention():
+    """Yield the set of inner kernels (``flash``, ``chunked``, ``ref``,
+    ``ring``) that :func:`attention` resolves to while a step is traced
+    under this context — what a trainer reports of its layers."""
+    global _RESOLVED
+    outer, _RESOLVED = _RESOLVED, set()
+    try:
+        yield _RESOLVED
+    finally:
+        _RESOLVED = outer
 
 
 def init_attention(key, cfg: ModelConfig, *, d_model: Optional[int] = None,
@@ -93,8 +114,12 @@ def sdpa_chunked(q: jax.Array, k: jax.Array, v: jax.Array, *,
     """Memory-efficient attention: q is processed in blocks (scan +
     rematerialized block body), so peak score memory is
     (B, H, block_q, T) instead of (B, H, S, T).  This is the pure-jnp
-    analogue of the Pallas flash kernel, used on non-TPU backends and in
-    the 512-device dry-runs."""
+    analogue of the Pallas flash kernel.  ``attention(impl="auto")``
+    takes it at S >= 1024 wherever :func:`resolve_impl` does not pick
+    the kernel: off TPU (CPU tests), under ``force_unroll`` (the
+    dry-run's roofline probes, whose ``cost_analysis`` counts nothing
+    inside a custom call), at a head dim off the 128 lanes, or with
+    heads that do not split over the mesh's ``model`` axis."""
     B, S, H, dh = q.shape
     T, KV = k.shape[1], k.shape[2]
     G = H // KV
@@ -109,7 +134,6 @@ def sdpa_chunked(q: jax.Array, k: jax.Array, v: jax.Array, *,
     # the rematerialized scan body (64x per layer-pass); one explicit gather
     # is tiny thanks to GQA (kv_dim << q_dim).  Without seq sharding the
     # pin is left off — it perturbs GSPMD's (cheaper) baseline layout.
-    from .flags import constrain_batch_only, seq_sharding_active
     if seq_sharding_active():
         kf = constrain_batch_only(k.astype(jnp.float32))
         vf = constrain_batch_only(v.astype(jnp.float32))
@@ -138,6 +162,45 @@ def sdpa_chunked(q: jax.Array, k: jax.Array, v: jax.Array, *,
     return jnp.moveaxis(ob, 0, 1).reshape(B, S, H, dh)
 
 
+def resolve_impl(S: int, cfg: ModelConfig) -> str:
+    """The inner kernel ``attention(impl="auto")`` takes for a sequence
+    of S tokens: the Pallas flash kernel on TPU at S >= 1024 when the
+    head dim fills the 128 lanes and both head counts split over the
+    ambient mesh's ``model`` axis (outside ``force_unroll``); else
+    ``sdpa_chunked`` at S >= 1024 and ``sdpa_ref`` below."""
+    if S < 1024:
+        return "ref"
+    mesh = current_mesh()
+    tp = mesh.shape.get("model", 1) if mesh is not None else 1
+    if (jax.default_backend() == "tpu" and not unroll_scans()
+            and cfg.dh % 128 == 0 and cfg.n_heads % tp == 0
+            and cfg.n_kv_heads % tp == 0):
+        return "flash"
+    return "chunked"
+
+
+def _flash_on_mesh(q: jax.Array, k: jax.Array, v: jax.Array, *,
+                   causal: bool, window: Optional[int]) -> jax.Array:
+    """The flash kernel under the ambient mesh.  GSPMD cannot split a
+    ``pallas_call``, so it runs in ``shard_map``: batch over the batch
+    axes, heads over ``model``, the sequence whole (a sequence-sharded
+    q/k/v is gathered on the way in)."""
+    from repro.kernels.ops import flash_attention as _flash
+
+    def local(q, k, v):
+        return _flash(q, k, v, causal=causal, window=window)
+
+    mesh = current_mesh()
+    if mesh is None:
+        return local(q, k, v)
+    bt = current_batch_axes()
+    split = bt and q.shape[0] % math.prod(mesh.shape[a] for a in bt) == 0
+    heads = "model" if mesh.shape.get("model", 1) > 1 else None
+    spec = P(bt if split else None, None, heads, None)
+    return jax.shard_map(local, mesh=mesh, in_specs=(spec, spec, spec),
+                         out_specs=spec, check_vma=False)(q, k, v)
+
+
 def attention(p, x: jax.Array, positions: jax.Array, cfg: ModelConfig, *,
               causal: bool = True,
               window: Optional[int] = None,
@@ -154,14 +217,15 @@ def attention(p, x: jax.Array, positions: jax.Array, cfg: ModelConfig, *,
     B, S, _ = x.shape
     q, k, v = _project_qkv(p, x, cfg, positions)
     if impl == "auto":
-        impl = "chunked" if S >= 1024 else "ref"
+        impl = resolve_impl(S, cfg)
+    if _RESOLVED is not None:
+        _RESOLVED.add(impl)
     if impl == "ring":
         from repro.kernels.ops import ring_flash_attention as _ring
         out = _ring(q, k, v, causal=causal, window=window,
                     axis_name=sp_axis, axis_size=sp_size)
     elif impl == "flash":
-        from repro.kernels.ops import flash_attention as _flash
-        out = _flash(q, k, v, causal=causal, window=window)
+        out = _flash_on_mesh(q, k, v, causal=causal, window=window)
     elif impl == "chunked":
         out = sdpa_chunked(q, k, v, causal=causal, window=window)
     else:

@@ -27,7 +27,7 @@ makes, so that no device holds more than the plan was sized for:
 from __future__ import annotations
 
 from collections import Counter
-from typing import List, Optional, Sequence
+from typing import Iterable, List, Optional, Sequence
 
 from repro.core.layerspec import LayerSpec
 from repro.core.plan import ParallelPlan
@@ -85,12 +85,15 @@ def policy_from_plan(cfg: ModelConfig, plan: ParallelPlan, *,
 
 
 def execution_line(plan: ParallelPlan, policy: ShardPolicy,
-                   mesh_shape) -> str:
+                   mesh_shape, attn: Iterable[str] = ()) -> str:
     """One line: the searched plan (pipeline degree, schedule,
     micro-batches, per-layer TP/SDP/CKPT counts) beside what the GSPMD
-    executor runs of it (policy and mesh), naming what it drops.
-    ``policy_from_plan`` keeps one stage whatever ``plan.pp_degree`` is,
-    and one TP, ZeRO and remat choice for every layer."""
+    executor runs of it (policy, mesh, and ``attn``, the attention
+    kernels the traced step's layers resolved to, ``none`` without
+    attention; see ``models.attention.recording_attention``), naming
+    what it drops.  ``policy_from_plan`` keeps one stage whatever
+    ``plan.pp_degree`` is, and one TP, ZeRO and remat choice for every
+    layer."""
     def counts(name: str) -> str:
         n = Counter(getattr(s, name) for s in plan.strategies)
         return ", ".join(f"{name}{k} x{v}" for k, v in sorted(n.items()))
@@ -106,7 +109,8 @@ def execution_line(plan: ParallelPlan, policy: ShardPolicy,
             f"{counts('sdp')}; ckpt x{sum(s.ckpt for s in plan.strategies)}"
             f" | executed: one GSPMD stage on mesh {dict(mesh_shape)}, "
             f"tp={policy.tp} zero={policy.zero} "
-            f"remat={any(policy.remat_segments or ())} | dropped: "
+            f"remat={any(policy.remat_segments or ())} "
+            f"attn={'+'.join(sorted(attn)) or 'none'} | dropped: "
             + (", ".join(dropped) or "nothing"))
 
 

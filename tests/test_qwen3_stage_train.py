@@ -38,14 +38,14 @@ def test_execution_line_names_the_dropped_pipeline(batch):
     from repro.runtime.plan_bridge import execution_line, policy_from_plan
     cfg, plan = _stage_plan(batch)
     line = execution_line(plan, policy_from_plan(cfg, plan),
-                          {"data": 2, "model": 2})
+                          {"data": 2, "model": 2}, {"flash"})
     searched, executed, dropped = line.split(" | ")
     assert searched.startswith(f"searched: pp2 {plan.schedule}")
     assert f"m={plan.n_micro}" in searched
     assert f"ckpt x{sum(s.ckpt for s in plan.strategies)}" in searched
     assert executed == ("executed: one GSPMD stage on mesh "
                         "{'data': 2, 'model': 2}, tp=True zero=True "
-                        "remat=True")
+                        "remat=True attn=flash")
     assert dropped.startswith(f"dropped: pp2 {plan.schedule}")
 
 
@@ -55,7 +55,7 @@ def test_execution_line_of_a_one_stage_plan_drops_no_pipeline():
     assert plan.pp_degree == 1
     line = execution_line(plan, policy_from_plan(cfg, plan),
                           {"data": 1, "model": 4})
-    assert line.endswith("| dropped: per-layer tp/sdp/ckpt")
+    assert line.endswith("attn=none | dropped: per-layer tp/sdp/ckpt")
 
 
 def test_trainer_prints_the_execution_line(capsys):

@@ -12,8 +12,10 @@ and the test workers all import this file.
 """
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
-from jax.sharding import NamedSharding, PartitionSpec as P, SingleDeviceSharding
+from jax.sharding import (AxisType, Mesh, NamedSharding, PartitionSpec as P,
+                          SingleDeviceSharding)
 
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.ring_attention import ring_flash_attention
@@ -68,6 +70,46 @@ def test_flash_attention_qwen3_4b_heads(one_chip, seq):
     compiled = jax.jit(lambda q, k, v: flash_attention(q, k, v, causal=True)
                        ).lower(q, kv, kv).compile()
     _assert_kernel(compiled)
+
+
+def _flash_grad(attend):
+    def loss(q, k, v):
+        return jnp.sum(attend(q, k, v).astype(jnp.float32))
+    return jax.jit(jax.grad(loss, argnums=(0, 1, 2)))
+
+
+def test_flash_attention_grad_at_the_4chip_cells_per_chip_shapes(one_chip):
+    """The forward and both backward kernels at what each chip of the
+    qwen3-4b-l9 four-chip cell attends: 8 rows, 16 query and 4 KV heads."""
+    q = _sds((8, 4096, 16, 128), jnp.bfloat16, one_chip)
+    kv = _sds((8, 4096, 4, 128), jnp.bfloat16, one_chip)
+    compiled = _flash_grad(lambda q, k, v: flash_attention(
+        q, k, v, causal=True)).lower(q, kv, kv).compile()
+    assert compiled.as_text().count("tpu_custom_call") >= 3
+
+
+def test_flash_attention_grad_in_shard_map_on_2x2(topo, monkeypatch):
+    """models/attention.py's mesh wrapper at the cell's global shapes on
+    (data 2, model 2): batch over data, heads over model.  The described
+    chip is not the default backend, so the test steers the wrapper off
+    interpret mode."""
+    from repro.kernels import ops
+    from repro.models.attention import _flash_on_mesh
+    from repro.models.flags import batch_sharding
+    monkeypatch.setattr(ops, "_interpret", lambda: False)
+    mesh = Mesh(np.array(topo.devices).reshape(2, 2), ("data", "model"),
+                axis_types=(AxisType.Auto,) * 2)
+    shard = NamedSharding(mesh, P("data", None, "model", None))
+    q = _sds((16, 4096, 32, 128), jnp.bfloat16, shard)
+    kv = _sds((16, 4096, 8, 128), jnp.bfloat16, shard)
+
+    def attend(q, k, v):
+        with batch_sharding(("data",), mesh=mesh):
+            return _flash_on_mesh(q, k, v, causal=True, window=None)
+
+    compiled = _flash_grad(attend).lower(q, kv, kv).compile()
+    _assert_kernel(compiled)
+    assert "all-gather" not in compiled.as_text()
 
 
 def test_ring_attention_4_chips_32k_per_shard(topo):
