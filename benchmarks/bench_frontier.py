@@ -7,11 +7,8 @@ return byte-identical plans at every budget — it is a pure restructuring of
 the same search — and the wall-clock ratio is the tentpole win: the stage
 DP runs once with a budget axis and the budget-independent memo caches
 (cost tables, reference costs, seed partitions) are shared across budgets
-instead of rebuilt per call.
-
-Also times the ``parallel=True`` (B, P) fan-out and checks its frontier,
-plans and aggregated cache telemetry (hits + misses == lookups) against
-the serial sweep.
+instead of rebuilt per call.  The sweep's cache telemetry must also add
+up (hits + misses == lookups).
 
 Results land in ``BENCH_frontier.json`` at the repo root.
 
@@ -67,9 +64,9 @@ def canonical(plan):
 
 def run_config(name, specs, cluster, tweaks, budgets, repeats):
     quant = max(budgets)
-    t_serial = t_sweep = t_parallel = float("inf")
-    serial_plans = frontier = par_frontier = None
-    stats = par_stats = {}
+    t_serial = t_sweep = float("inf")
+    serial_plans = frontier = None
+    stats = {}
     for _ in range(max(1, repeats)):
         # ---- N independent serial optimize() calls ---------------------
         t0 = time.perf_counter()
@@ -84,22 +81,12 @@ def run_config(name, specs, cluster, tweaks, budgets, repeats):
         frontier = opt.sweep_budgets(budgets)
         t_sweep = min(t_sweep, time.perf_counter() - t0)
         stats = dict(opt.stats)
-        # ---- parallel (B, P) fan-out -----------------------------------
-        opt = make_opt(specs, cluster, tweaks)
-        t0 = time.perf_counter()
-        par_frontier = opt.sweep_budgets(budgets, parallel=True)
-        t_parallel = min(t_parallel, time.perf_counter() - t0)
-        par_stats = dict(opt.stats)
 
     identical = all(
         canonical(p.plan) == canonical(serial_plans[p.budget_bytes])
         for p in frontier.points)
-    par_identical = all(
-        canonical(p.plan) == canonical(q.plan)
-        for p, q in zip(par_frontier.points, frontier.points))
-    counters_ok = all(
-        s["stage_cache_hits"] + s["stage_cache_misses"] == s["stage_searches"]
-        for s in (stats, par_stats))
+    counters_ok = (stats["stage_cache_hits"] + stats["stage_cache_misses"]
+                   == stats["stage_searches"])
     speedup = t_serial / t_sweep if t_sweep > 0 else float("inf")
     return {
         "n_layers": len(specs),
@@ -107,10 +94,8 @@ def run_config(name, specs, cluster, tweaks, budgets, repeats):
         "budgets_gb": [b / GB for b in budgets],
         "serial_seconds": round(t_serial, 4),
         "sweep_seconds": round(t_sweep, 4),
-        "parallel_seconds": round(t_parallel, 4),
         "speedup": round(speedup, 2),
         "identical_plans": bool(identical),
-        "parallel_identical": bool(par_identical),
         "cache_counters_consistent": bool(counters_ok),
         "throughputs": frontier.throughputs(),
         "knee_budgets_gb": [p.budget_bytes / GB
@@ -118,11 +103,7 @@ def run_config(name, specs, cluster, tweaks, budgets, repeats):
         "sweep_stats": {k: stats.get(k) for k in
                         ("stage_searches", "stage_cache_hits",
                          "stage_cache_misses", "table_builds", "table_hits")},
-        "parallel_stats": {k: par_stats.get(k) for k in
-                           ("stage_searches", "stage_cache_hits",
-                            "stage_cache_misses", "table_builds",
-                            "table_hits")},
-    }, identical and par_identical and counters_ok, speedup
+    }, identical and counters_ok, speedup
 
 
 def main(argv=None) -> int:
@@ -145,7 +126,6 @@ def main(argv=None) -> int:
         ok = ok and row_ok
         print(f"{name}: serial {row['serial_seconds']:.3f}s  "
               f"sweep {row['sweep_seconds']:.3f}s  "
-              f"parallel {row['parallel_seconds']:.3f}s  "
               f"speedup {speedup:.1f}x  identical={row['identical_plans']}")
         if not row_ok:
             print(f"ERROR: {name}: sweep diverged from serial optimizes "

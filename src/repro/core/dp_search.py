@@ -288,14 +288,9 @@ def _finish_budget_scan(
     E: int,
 ) -> List[StageSearchResult]:
     """Backtracking + per-budget descending E_fwd scan over finished DP
-    tables (the tail of ``dp_search_stage_budgets``, shared verbatim with
-    the batched entry so both produce identical results by construction).
+    tables (the tail of ``dp_search_stage_budgets``).
 
-    ``states`` holds the per-layer C tables for the *real* layers only;
-    their budget-bin height may exceed ``E + 1`` (the batched path stacks
-    jobs to a shared height) — rows above ``E`` are simply never read, and
-    rows ``<= E`` are independent of table height because every transition
-    reads only equal-or-lower bins (weights are non-negative).
+    ``states`` holds the per-layer C tables, each ``(E + 1, S)``.
     """
     L = len(states)
     C = states[-1]
@@ -385,154 +380,6 @@ def _finish_budget_scan(
                 break
         out.append(found)
     return out
-
-
-# --------------------------------------------------------------------------
-# batched entry — many stage searches, one stacked forward pass
-# --------------------------------------------------------------------------
-
-def dp_search_stage_budgets_batch(
-    jobs: Sequence[Tuple[CostTables, int]],
-    strategies: Sequence[Strategy],
-    budgets: Sequence[float],
-    *,
-    quant_bytes: float,
-    n_bins: int = 256,
-) -> List[List[StageSearchResult]]:
-    """Run many independent stage searches as ONE stacked NumPy DP.
-
-    ``jobs`` is a sequence of ``(tables, n_micro)`` pairs — each ``tables``
-    holds the (L_j, S) cost arrays of one stage (already sliced at the
-    right ``B_m`` / inflight), all over the *same* strategy set and the
-    same budget axis.  The per-layer DP transition is evaluated for every
-    job at once on ``(N, E+1, S)`` arrays instead of N separate Python
-    loops — the ``backend="vectorized"`` hot path of the optimizer.
-
-    Byte-identity with N separate ``dp_search_stage_budgets`` calls:
-
-    * jobs are stacked by *front*-padding shorter stages with zero layers
-      (zero time/weight/reshard).  A zero prefix leaves the DP table
-      identically zero, and the transition into the first real layer
-      reproduces the unpadded initialization exactly (the cross term is
-      ``0 + reshard >= 0 = same-group``, so ``min`` keeps 0, and the shift
-      by ``w`` marks ``e < w`` infeasible — the serial ``l == 0`` case);
-    * the stacked tables use the tallest job's bin count, but each job's
-      scan/backtrack runs at its own ``E_j``; rows ``<= E_j`` never read
-      higher rows (non-negative weights), so extra height is inert;
-    * the finisher is literally the serial one (``_finish_budget_scan``)
-      on per-job views of the stacked states.
-    """
-    budgets = [float(b) for b in budgets]
-    if not jobs or not budgets:
-        return [[] for _ in jobs]
-    quant = float(quant_bytes)
-    S = len(strategies)
-    bin_bytes = max(quant / n_bins, 1.0)
-    caps = [_bin_cap(b, quant, bin_bytes, n_bins) for b in budgets]
-    nb_max = max(caps)
-
-    (group_of, G, group_members, contiguous, group_starts,
-     uniform) = _group_info(strategies)
-
-    empty = [StageSearchResult(True, 0.0, 0.0, [], 0.0, 0.0, 0.0)
-             for _ in budgets]
-    infeasible = [StageSearchResult(False, INF, INF, [], INF, INF, 0.0)
-                  for _ in budgets]
-
-    # ---- per-job prep: amortized time, weights, own scan height ---------
-    prepped = []          # (job_index, tables, time, w, E_j)
-    out: List[Optional[List[StageSearchResult]]] = [None] * len(jobs)
-    for i, (tb, n_micro) in enumerate(jobs):
-        L = tb.time_sync.shape[0]
-        if L == 0:
-            out[i] = list(empty)
-            continue
-        time = tb.time_nosync + (tb.time_sync - tb.time_nosync) / max(1, n_micro)
-        w = np.ceil((tb.mem_f + tb.mem_ms) / bin_bytes).astype(np.int64)
-        w_valid = np.where(w <= nb_max, w, -1)
-        per_layer_max = w_valid.max(axis=1)
-        if (per_layer_max < 0).any():   # some layer fits under no strategy
-            out[i] = list(infeasible)
-            continue
-        E_j = int(min(nb_max, per_layer_max.sum()))
-        prepped.append((i, tb, time, w, E_j))
-    if not prepped:
-        return out  # type: ignore[return-value]
-
-    # ---- stack with zero front-padding to a shared (Lmax, N*S) ----------
-    # jobs live side by side as column blocks so every transition below is
-    # literally the serial one on a wider table — including its cached
-    # shifted-gather flat indices (homogeneous stacks repeat weight rows
-    # across both layers and jobs, so the cache hits constantly)
-    N = len(prepped)
-    Lmax = max(tb.time_sync.shape[0] for _, tb, _, _, _ in prepped)
-    E = max(E_j for *_, E_j in prepped)
-    W = N * S
-    t_stk = np.zeros((Lmax, W))
-    w_stk = np.zeros((Lmax, W), dtype=np.int64)
-    r_stk = np.zeros((Lmax, W))
-    pads = []
-    for k, (_, tb, time, w, _) in enumerate(prepped):
-        pad = Lmax - time.shape[0]
-        pads.append(pad)
-        t_stk[pad:, k * S:(k + 1) * S] = time
-        w_stk[pad:, k * S:(k + 1) * S] = w
-        r_stk[pad:, k * S:(k + 1) * S] = tb.reshard
-
-    # ---- stacked forward DP (the serial transition on N*S columns) ------
-    ebins = np.arange(E + 1)
-    cols = np.arange(W)
-    shift_cache: Dict[bytes, Tuple[np.ndarray, np.ndarray]] = {}
-
-    def shift_for(l: int):
-        key = w_stk[l].tobytes()
-        cached = shift_cache.get(key)
-        if cached is None:
-            idx = ebins[:, None] - w_stk[l][None, :]    # source bin per (e, c)
-            invalid = (idx < 0).ravel()                 # also when w > E
-            np.clip(idx, 0, E, out=idx)
-            flat = (idx * W + cols[None, :]).ravel()
-            cached = shift_cache[key] = (flat, invalid)
-        return cached
-
-    states: List[np.ndarray] = []
-    C = None
-    for l in range(Lmax):
-        flat, invalid = shift_for(l)
-        if l == 0:
-            Cn = np.broadcast_to(t_stk[0][None, :], (E + 1, W)).copy()
-        else:
-            C3 = C.reshape(E + 1, N, S)
-            if uniform and S == 2 * G:          # ckpt pairs: one binary ufunc
-                red = np.minimum(C3[:, :, ::2], C3[:, :, 1::2])
-            elif uniform:
-                red = C3.reshape(E + 1, N, G, S // G).min(axis=3)
-            elif contiguous:
-                red = np.minimum.reduceat(C3, group_starts, axis=2)
-            else:
-                red = np.empty((E + 1, N, G))
-                for g, members in enumerate(group_members):
-                    red[:, :, g] = C3[:, :, members].min(axis=2)
-            best_all = red.min(axis=2)                          # (E+1, N)
-            best_grp = red[:, :, group_of]                      # (E+1, N, S)
-            cross = (best_all[:, :, None]
-                     + r_stk[l].reshape(N, S)[None, :, :])
-            val = (np.minimum(best_grp, cross).reshape(E + 1, W)
-                   + t_stk[l][None, :])
-            Cn = val.ravel().take(flat).reshape(E + 1, W)
-        Cn.ravel()[invalid] = INF
-        states.append(Cn)
-        C = Cn
-
-    # ---- per-job serial finisher on views of the stacked states ---------
-    for k, (i, tb, _, w, E_j) in enumerate(prepped):
-        pad = pads[k]
-        out[i] = _finish_budget_scan(
-            [states[l][:, k * S:(k + 1) * S] for l in range(pad, Lmax)],
-            w, strategies, group_of, group_members,
-            tb.time_sync, tb.time_nosync, tb.mem_f, tb.mem_b, tb.mem_ms,
-            tb.reshard, budgets, caps, bin_bytes, E_j)
-    return out  # type: ignore[return-value]
 
 
 # --------------------------------------------------------------------------

@@ -142,72 +142,3 @@ class PlanFrontier:
     @staticmethod
     def loads(s: str) -> "PlanFrontier":
         return PlanFrontier.from_json(json.loads(s))
-
-
-# --------------------------------------------------------------------------
-# batch-axis dominance frontier (search-time pruning)
-# --------------------------------------------------------------------------
-
-@dataclasses.dataclass(frozen=True)
-class CandidateBound:
-    """Certified optimistic bounds for one unexplored (B, P) candidate.
-
-    ``tpt_upper`` over-estimates the best throughput any plan of the
-    candidate can reach (an ideal-balance cost lower bound turned into a
-    samples/s upper bound); ``mem_lower`` under-estimates the peak stage
-    memory of its *cheapest* strategy assignment.  Both must be sound —
-    the pruner's byte-identity guarantee leans on them — so they are built
-    from per-layer minima of the exact cost tables (see
-    ``GalvatronOptimizer._candidate_bound`` for the derivation)."""
-
-    tpt_upper: float              # samples/s, >= any achievable throughput
-    mem_lower: float              # bytes, <= any achievable peak stage memory
-
-
-class DominanceFrontier:
-    """Running per-budget dominance frontier over the batch axis.
-
-    Mirrors the budget-axis machinery one level up: as the B × P sweep
-    explores candidates in grid order it records the best throughput
-    achieved so far *under each budget* (:meth:`observe`); an unexplored
-    candidate whose optimistic :class:`CandidateBound` cannot beat that
-    incumbent (:meth:`dominated`) — or cannot even fit
-    (:meth:`infeasible`) — is skipped without running its inner DP.
-
-    Soundness of skipping, per budget ``k``:
-
-    * *infeasible*: ``mem_lower > budgets[k]`` means every strategy chain
-      of the candidate exceeds the budget, so the serial search would have
-      returned no plan for ``k`` — skipping changes nothing.
-    * *dominated*: the serial sweep replaces its incumbent only on a
-      *strictly* better throughput, and incumbents only improve over time,
-      so a candidate with ``tpt_upper <= best[k]`` at skip time can never
-      displace the final answer.
-
-    The interaction with the two-consecutive-OOM batch stop is handled by
-    the optimizer (a dominated-but-feasible candidate may still need a
-    *forced* run to decide OOM bookkeeping — see ``_sweep_axis``).
-    """
-
-    def __init__(self, budgets):
-        self.budgets = tuple(float(b) for b in budgets)
-        self.best = [0.0] * len(self.budgets)
-
-    def observe(self, k: int, throughput: float) -> None:
-        """Record a plan actually found under budget ``k``."""
-        if throughput > self.best[k]:
-            self.best[k] = throughput
-
-    def infeasible(self, k: int, bound: CandidateBound) -> bool:
-        return bound.mem_lower > self.budgets[k]
-
-    def dominated(self, k: int, bound: CandidateBound) -> bool:
-        return self.best[k] > 0.0 and bound.tpt_upper <= self.best[k]
-
-    def classify(self, k: int, bound: CandidateBound) -> str:
-        """``"infeasible"`` / ``"dominated"`` / ``"live"`` for budget k."""
-        if self.infeasible(k, bound):
-            return "infeasible"
-        if self.dominated(k, bound):
-            return "dominated"
-        return "live"

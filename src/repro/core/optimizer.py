@@ -14,24 +14,17 @@ every row of the paper's tables is produced by this one class.
 """
 from __future__ import annotations
 
-import copy
 import dataclasses
-import os
 import time as _time
-from concurrent.futures import (ProcessPoolExecutor, ThreadPoolExecutor,
-                                as_completed)
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
 from .cost_model import (CostModel, CostModelConfig, CostTables,
-                         _SP_INVALID_TIME, _drain_divisor,
-                         pipeline_iter_time)
+                         _SP_INVALID_TIME, pipeline_iter_time)
 from .decision_tree import SearchSpace, construct_search_space
-from .dp_search import (StageSearchResult, dp_search_stage_budgets,
-                        dp_search_stage_budgets_batch)
-from .frontier import (CandidateBound, DominanceFrontier, FrontierPoint,
-                       PlanFrontier)
+from .dp_search import StageSearchResult, dp_search_stage_budgets
+from .frontier import FrontierPoint, PlanFrontier
 from .hardware import ClusterSpec
 from .layerspec import LayerSpec
 from .pipeline_balance import (PartitionEval, adjust_partition,
@@ -43,9 +36,6 @@ from .plan import ParallelPlan
 from .strategy import EP, PARADIGMS, SP, Strategy, strategy_set_id
 
 INF = float("inf")
-
-#: legal values of ``OptimizerConfig.search_backend`` / ``--backend``
-SEARCH_BACKENDS = ("serial", "threads", "processes", "vectorized")
 
 
 def normalize_batch_grid(grid: Optional[Sequence[int]]
@@ -115,7 +105,8 @@ class OptimizerConfig:
     fixed_pp: Optional[int] = None
     max_adjust_iters: int = 32                 # BMW queue budget per (B, P)
     # search-engine speed knobs (both default on; turning them off recovers
-    # the original per-candidate / per-pair behaviour for benchmarking)
+    # the scalar cost path and the uncached DP that tests/test_search_cache.py
+    # compares against)
     enable_stage_cache: bool = True            # memoize dp_search_stage results
     vectorized_cost: bool = True               # batched (L,S) cost tables
     # memory-budget constraint in bytes; None => cluster.budget().  Distinct
@@ -126,30 +117,8 @@ class OptimizerConfig:
     # (single-budget searches then quantize on their own budget — the
     # pre-frontier behaviour)
     quant_bytes: Optional[float] = None
-    # -- cluster-scale engine knobs ------------------------------------
-    # how the outer (B, P) candidates execute: "serial" (the oracle),
-    # "threads" / "processes" (pooled fan-out), or "vectorized" (all of a
-    # partition's stage DPs batched into one stacked NumPy evaluation).
-    # Every backend returns plans byte-identical to "serial".
-    search_backend: str = "serial"
-    # pool size for threads/processes (None => one worker per core)
-    jobs: Optional[int] = None
-    # frontier-guided batch-axis pruning: skip (B, P) candidates whose
-    # certified optimistic bound is dominated or provably over-budget
-    # (needs vectorized_cost for the bound tables; plans stay identical)
-    prune_batch_axis: bool = False
 
     def __post_init__(self):
-        if self.search_backend not in SEARCH_BACKENDS:
-            raise ValueError(
-                f"search_backend must be one of {SEARCH_BACKENDS}, "
-                f"got {self.search_backend!r}")
-        if self.search_backend == "vectorized" and not self.vectorized_cost:
-            raise ValueError(
-                "search_backend='vectorized' batches the stage DP over the "
-                "(L, S) cost tables and therefore needs vectorized_cost=True")
-        if self.jobs is not None and int(self.jobs) < 1:
-            raise ValueError(f"jobs must be a positive integer, got {self.jobs}")
         self.batch_grid = normalize_batch_grid(self.batch_grid)
 
 
@@ -161,30 +130,6 @@ def default_batch_grid(max_batch: int) -> List[int]:
     return grid
 
 
-_MISS = object()
-
-
-class _ShardCache(dict):
-    """Worker-local memo shard (parallel sweep, DESIGN.md §6).
-
-    Reads fall through to the shared base cache (filled before the pool
-    fanned out, never mutated while workers run); writes stay local and are
-    merged back into the base once the worker's (B, P) candidate is done.
-    Iteration / ``update()`` expose only the local writes, which is exactly
-    what the merge wants.
-    """
-
-    def __init__(self, base: dict):
-        super().__init__()
-        self._base = base
-
-    def get(self, key, default=None):
-        v = super().get(key, _MISS)
-        if v is not _MISS:
-            return v
-        return self._base.get(key, default)
-
-
 class GalvatronOptimizer:
     def __init__(self, specs: Sequence[LayerSpec], cluster: ClusterSpec,
                  config: Optional[OptimizerConfig] = None,
@@ -193,7 +138,6 @@ class GalvatronOptimizer:
         self.specs = list(specs)
         self.cluster = cluster
         self.cfg = config or OptimizerConfig()
-        self._cost_config = cost_config      # kept for process-pool workers
         self.cost = CostModel(cluster, cost_config,
                               profiled_times=profiled_times)
         paradigms = tuple(self.cfg.paradigms)
@@ -216,12 +160,7 @@ class GalvatronOptimizer:
             "stage_cache_misses": 0,
             "table_builds": 0,          # full-model (L,S) cost-table builds
             "table_hits": 0,
-            "bound_evals": 0,           # (B, P) optimistic-bound builds
             "bp_candidates": 0,         # (B, P) outer candidates considered
-            "bp_pruned_infeasible": 0,  # skipped: cannot fit any live budget
-            "bp_pruned_dominated": 0,   # deferred: cannot beat incumbent
-            "bp_forced": 0,             # deferred candidates run anyway (OOM
-                                        # bookkeeping; see _sweep_axis)
             "search_seconds": 0.0,
         }
         # memo caches: stage-search results keyed on (layer-range, B_m,
@@ -238,12 +177,6 @@ class GalvatronOptimizer:
         self._table_cache: Dict[Tuple, CostTables] = {}
         self._ref_cache: Dict[Tuple, Tuple[np.ndarray, np.ndarray]] = {}
         self._part_cache: Dict[Tuple, Tuple[List[int], List[int]]] = {}
-        # (B, P) -> CandidateBound for the pruning frontier; budget-
-        # independent (bounds compare against the axis at classify time)
-        self._bound_cache: Dict[Tuple[int, int], CandidateBound] = {}
-        # True only while _sweep_axis runs the "vectorized" backend:
-        # _eval_partition then routes stage searches through the stacked DP
-        self._batch_eval = False
         # active budget axis: every stage search returns one result per
         # budget (optimize() runs a 1-point axis; sweep_budgets() the full
         # frontier).  The quantization grid is pinned per axis so results
@@ -381,61 +314,6 @@ class GalvatronOptimizer:
             self._stage_cache[key] = res
         return res
 
-    def _stage_search_batch(self, reqs: Sequence[Tuple[int, int, int]],
-                            strategies: List[Strategy], sid: int, B_m: float,
-                            n_micro: int
-                            ) -> List[Tuple[StageSearchResult, ...]]:
-        """All of a partition's stage searches as ONE stacked DP.
-
-        ``reqs`` is ``[(a, b, inflight)]`` — one entry per pipeline stage.
-        Cache lookups, hit/miss telemetry and writes mirror the serial
-        per-stage loop exactly: the first in-batch occurrence of a key is
-        the miss, later duplicates are the hits the serial loop would have
-        scored against the first occurrence's fresh memo write.  Results
-        are byte-identical to per-request :meth:`_stage_search` calls
-        (``dp_search_stage_budgets_batch``'s front-padding proof).
-        """
-        out: List[Optional[Tuple[StageSearchResult, ...]]] = [None] * len(reqs)
-        pending: Dict[Tuple, List[int]] = {}   # key -> out indices wanting it
-        job_keys: List[Tuple] = []
-        job_reqs: List[Tuple[int, int, int]] = []
-        for i, (a, b, infl) in enumerate(reqs):
-            self.stats["stage_searches"] += 1
-            key = (self._layer_sig[a:b], B_m, infl, n_micro, sid)
-            if self.cfg.enable_stage_cache:
-                res = self._stage_cache.get(key)
-                if res is not None:
-                    self.stats["stage_cache_hits"] += 1
-                    out[i] = res
-                    continue
-                if key in pending:
-                    self.stats["stage_cache_hits"] += 1
-                    pending[key].append(i)
-                    continue
-                self.stats["stage_cache_misses"] += 1
-            want = pending.get(key)
-            if want is not None:     # cache-disabled duplicate: share the job
-                want.append(i)
-                continue
-            pending[key] = [i]
-            job_keys.append(key)
-            job_reqs.append((a, b, infl))
-        if job_keys:
-            jobs = []
-            for a, b, infl in job_reqs:
-                tb = self._full_tables(strategies, sid, B_m, infl)
-                jobs.append((tb.rows(a, b), n_micro))
-            batch = dp_search_stage_budgets_batch(
-                jobs, strategies, self._budget_axis,
-                quant_bytes=self._quant, n_bins=self.cfg.n_bins)
-            for key, res_list in zip(job_keys, batch):
-                res = tuple(res_list)
-                if self.cfg.enable_stage_cache:
-                    self._stage_cache[key] = res
-                for i in pending[key]:
-                    out[i] = res
-        return out
-
     def _strategies_for(self, P: int) -> Tuple[List[Strategy], int]:
         strategies = self.search_space.strategies(P)
         if self.cfg.fixed_strategy is not None:
@@ -444,8 +322,8 @@ class GalvatronOptimizer:
 
     def clear_cache(self) -> None:
         """Drop every memo cache (stage searches, cost tables, reference
-        costs, seed partitions, pruning bounds, and the cost model's
-        collective-coefficient memo) and zero the telemetry counters.  The caches
+        costs, seed partitions, and the cost model's collective-coefficient
+        memo) and zero the telemetry counters.  The caches
         persist across ``optimize()`` calls by design; call this when the
         instance's cost inputs change under it (e.g. mutated
         ``profiled_times``).  A cleared optimizer behaves exactly like a
@@ -454,7 +332,6 @@ class GalvatronOptimizer:
         self._table_cache.clear()
         self._ref_cache.clear()
         self._part_cache.clear()
-        self._bound_cache.clear()
         self.cost.clear_cache()
         for k in self.stats:
             self.stats[k] = 0.0 if k == "search_seconds" else 0
@@ -523,14 +400,8 @@ class GalvatronOptimizer:
         bounds = stage_bounds(partition)
         infls = [inflight_microbatches(i, P, m, schedule, vpp)
                  for i in range(P)]
-        if self._batch_eval:
-            per_stage = self._stage_search_batch(
-                [(a, b, infl) for (a, b), infl in zip(bounds, infls)],
-                strategies, sid, B_m, m)
-        else:
-            per_stage = [self._stage_search(a, b, strategies, sid, B_m,
-                                            infl, m)
-                         for (a, b), infl in zip(bounds, infls)]
+        per_stage = [self._stage_search(a, b, strategies, sid, B_m, infl, m)
+                     for (a, b), infl in zip(bounds, infls)]
         out: List[Tuple[float, PartitionEval, List[Strategy]]] = []
         for k in range(K):
             stage_times, stage_ns, stage_mems, all_strats = [], [], [], []
@@ -578,68 +449,6 @@ class GalvatronOptimizer:
         if not cands:
             cands = [B]
         return cands
-
-    # ------------------------------------------------------------------
-    # frontier-guided batch-axis pruning (optimistic candidate bounds)
-    # ------------------------------------------------------------------
-    def _max_drain_divisor(self) -> float:
-        """Largest bubble-shrink factor any configured schedule can reach —
-        the sound (most optimistic) divisor for the pruning bound's drain
-        term (``_drain_divisor``: 3 for zb-h1, V for interleaved)."""
-        names = (tuple(self.cfg.schedules) if self.cfg.schedules
-                 else (self.cfg.schedule,))
-        div = 1.0
-        for name in names:
-            if name == "1f1b-interleaved":
-                vs = [int(v) for v in self.cfg.vpp_candidates if int(v) > 1]
-                if vs:
-                    div = max(div, _drain_divisor(max(vs), name))
-            else:
-                div = max(div, _drain_divisor(1, name))
-        return div
-
-    def _candidate_bound(self, B: int, P: int) -> CandidateBound:
-        """Certified optimistic bounds for the (B, P) outer candidate,
-        cached per pair (budget-independent).
-
-        Throughput upper bound: for any partition, schedule (divisor
-        ``div <= div_max``) and micro-batch count ``m``, the iteration time
-        satisfies ``T >= (m-1)·max(Cns) + max(Cns) + (ΣCns - max(Cns))/div
-        >= m·ΣCns/P + (P-1)·t_min/div_max`` — sync/p2p/reshard terms only
-        add, ``max >= mean``, and each of the other ``P-1`` stages holds at
-        least one layer costing at least ``t_min`` (the cheapest layer's
-        cheapest strategy's no-sync time).  With ``ΣCns >= Tns_min`` (sum
-        of per-layer minima) and maximizing ``B / lb`` over the candidate
-        micro-batch counts, no plan of this candidate can beat the result.
-
-        Memory lower bound: every stage's exact DP memory ``e_all`` is the
-        sum of its layers' ``mem_f·inflight + mem_ms`` for the chosen
-        strategies, and ``inflight >= 1``, so the peak stage memory is at
-        least ``max(Σ_l min_s mem_ls / P, max_l min_s mem_ls)``; minimized
-        over ``m`` (``mem`` depends on ``B/m``).  The DP's acceptance
-        conditions each imply an exact fit (``e_all <= budget``), so
-        ``mem_lower > budget`` proves the serial search returns no plan.
-        """
-        bd = self._bound_cache.get((B, P))
-        if bd is not None:
-            return bd
-        self.stats["bound_evals"] += 1
-        strategies, sid = self._strategies_for(P)
-        div_max = self._max_drain_divisor()
-        tpt_ub, mem_lb = 0.0, INF
-        for m in self._micro_candidates(B, P):
-            tb = self._full_tables(strategies, sid, B / m, 1)
-            tmin = tb.time_nosync.min(axis=1)              # (L,)
-            iter_lb = m * float(tmin.sum()) / P
-            if P > 1:
-                iter_lb += (P - 1) * float(tmin.min()) / div_max
-            tpt_ub = max(tpt_ub, B / iter_lb if iter_lb > 0.0 else INF)
-            mem_vec = (tb.mem_f + tb.mem_ms).min(axis=1)   # (L,)
-            mem_lb = min(mem_lb,
-                         max(float(mem_vec.sum()) / P, float(mem_vec.max())))
-        bd = CandidateBound(tpt_upper=tpt_ub, mem_lower=mem_lb)
-        self._bound_cache[(B, P)] = bd
-        return bd
 
     # ------------------------------------------------------------------
     def _search_pp(self, B: int, P: int) -> Optional[List[Optional[ParallelPlan]]]:
@@ -766,9 +575,6 @@ class GalvatronOptimizer:
                                 verbose=verbose)[0]
 
     def sweep_budgets(self, budgets: Sequence[float], *,
-                      parallel: bool = False,
-                      max_workers: Optional[int] = None,
-                      backend: Optional[str] = None,
                       verbose: bool = False) -> PlanFrontier:
         """Compute the throughput-vs-memory frontier over ``budgets`` in
         ~one search (DESIGN.md §6).
@@ -777,9 +583,9 @@ class GalvatronOptimizer:
         budget-independent caches (cost tables, reference costs, seed
         partitions) are shared, so a K-point sweep costs close to a single
         ``optimize()`` instead of K of them.  Each budget's plan is
-        byte-identical to a serial ``optimize()`` at that budget on the
-        same quantization grid (``quant_bytes = max(budgets)`` unless
-        pinned in the config).
+        byte-identical to an ``optimize()`` at that budget on the same
+        quantization grid (``quant_bytes = max(budgets)`` unless pinned in
+        the config).
 
         Grid-resolution tradeoff: the DP resolves memory in
         ``quant_bytes / n_bins`` steps, so on a wide sweep the small
@@ -790,22 +596,8 @@ class GalvatronOptimizer:
         dedicated-search resolution — the larger budgets' scans then span
         proportionally more bins, costing more DP time.
 
-        ``backend`` selects how the independent (B, P) outer candidates
-        execute — ``"threads"`` / ``"processes"`` fan them over a pool
-        (workers write to private cache shards merged back with their
-        hit/miss telemetry), ``"vectorized"`` batches each partition's
-        stage DPs into one stacked NumPy evaluation.  Every backend's
-        plans are byte-identical to the ``"serial"`` oracle, in any
-        interleaving.  ``parallel=True`` is the PR-4-era spelling of
-        ``backend="threads"``.
-
         Args:
           budgets: memory budgets in bytes (deduplicated and sorted).
-          parallel: fan (B, P) candidates over a thread pool.
-          max_workers: pool size for pooled backends (default:
-            ``cfg.jobs``, else one per core).
-          backend: execution backend override (default:
-            ``cfg.search_backend``, or ``"threads"`` when ``parallel``).
           verbose: print every improving (B, P, budget) candidate.
 
         Returns:
@@ -820,8 +612,7 @@ class GalvatronOptimizer:
         axis = tuple(sorted({float(b) for b in budgets}))
         if not axis:
             raise ValueError("sweep_budgets needs at least one budget")
-        plans = self._sweep_axis(axis, verbose=verbose, parallel=parallel,
-                                 max_workers=max_workers, backend=backend)
+        plans = self._sweep_axis(axis, verbose=verbose)
         points = [FrontierPoint(budget_bytes=b, plan=p,
                                 predicted_throughput=(p.est_throughput
                                                       if p else 0.0))
@@ -830,39 +621,13 @@ class GalvatronOptimizer:
                             search_stats=dict(self.stats))
 
     def _sweep_axis(self, axis: Tuple[float, ...], *, verbose: bool = False,
-                    parallel: bool = False,
-                    max_workers: Optional[int] = None,
-                    backend: Optional[str] = None,
                     ) -> List[Optional[ParallelPlan]]:
         """Shared Alg. 1 outer loop over a budget axis: per-budget best
-        plans, with the per-budget OOM early-stop of the serial search (a
+        plans over the batch grid x PP degrees, in grid order.  A budget's
+        incumbent is replaced only by a strictly better throughput, and a
         budget that OOMed at two consecutive batch sizes stops growing B —
-        exactly when its serial counterpart would have).
-
-        The candidate execution backend (serial / threads / processes /
-        vectorized) and the dominance-frontier pruning are both plan-
-        preserving: every path below returns plans byte-identical to the
-        serial oracle.  Pruning soundness rests on three pillars:
-
-        * *infeasible* skips are final — ``CandidateBound.mem_lower``
-          exceeding a budget proves the serial search would have found no
-          plan there, so skipping contributes exactly nothing;
-        * *dominated* candidates cannot displace the incumbent (the bound
-          certifies their best throughput cannot beat a best that only
-          grows, and the sweep improves on strict ``>`` only), but their
-          *feasibility* still feeds the two-consecutive-OOM stop — so they
-          are deferred, and **forced** to run whenever a budget they were
-          deferred for found nothing else this round;
-        * forced candidates merge after the live ones, which is safe
-          because their plans provably never update ``best`` (order only
-          matters for ``best``; ``found`` is an order-free OR).
-        """
+        exactly when a dedicated search at that budget would have."""
         t0 = _time.time()
-        backend = backend or ("threads" if parallel
-                              else self.cfg.search_backend)
-        if backend not in SEARCH_BACKENDS:
-            raise ValueError(f"unknown search backend {backend!r}; "
-                             f"expected one of {SEARCH_BACKENDS}")
         self._set_budget_axis(axis)
         K = len(axis)
         grid = list(normalize_batch_grid(self.cfg.batch_grid)
@@ -870,254 +635,41 @@ class GalvatronOptimizer:
         pp_degrees = [P for P in ([self.cfg.fixed_pp] if self.cfg.fixed_pp
                                   else sorted(self.search_space.per_pp))
                       if P is not None and self.cluster.n_devices % P == 0]
-        prune = bool(self.cfg.prune_batch_axis and self.cfg.vectorized_cost)
-        frontier = DominanceFrontier(axis) if prune else None
-        pool = (_CandidatePool(self, backend, max_workers or self.cfg.jobs)
-                if backend in ("threads", "processes") else None)
-        self._batch_eval = (backend == "vectorized")
         best: List[Optional[ParallelPlan]] = [None] * K
         active = [True] * K
-        try:
-            eager: Dict[Tuple[int, int],
-                        Optional[List[Optional[ParallelPlan]]]] = {}
-            if pool is not None and not prune:
-                # eager full fan-out: every (B, P) computed up front (even
-                # past a budget's OOM stopping point — the merge below
-                # re-applies the serial stopping rule, so nothing changes)
-                eager = pool.run_many([(B, P) for B in grid
-                                       for P in pp_degrees])
-            consecutive_oom = [0] * K
-            L = len(self.specs)
-            for B in grid:
-                if not any(active):
-                    break
-                found = [False] * K
-
-                def merge(P, plans, B=B, found=found):
-                    if plans is None:
-                        return
-                    for k in range(K):
-                        if not active[k] or plans[k] is None:
-                            continue
-                        found[k] = True
-                        if frontier is not None:
-                            frontier.observe(k, plans[k].est_throughput)
-                        if (best[k] is None or plans[k].est_throughput
-                                > best[k].est_throughput):
-                            best[k] = plans[k]
-                            if verbose:
-                                print(
-                                    f"[B={B} P={P} "
-                                    f"budget={axis[k]/2**30:.1f}G] "
-                                    f"tpt={plans[k].est_throughput:.2f} "
-                                    f"{plans[k].summary()}")
-
-                if not prune:
-                    for P in pp_degrees:
-                        self.stats["bp_candidates"] += 1
-                        merge(P, eager[(B, P)] if pool is not None
-                              else self._search_pp(B, P))
-                else:
-                    # classify this B's candidates against the frontier
-                    # built from all previous batch sizes
-                    run_list: List[int] = []
-                    deferred: List[Tuple[int, List[int]]] = []
-                    for P in pp_degrees:
-                        self.stats["bp_candidates"] += 1
-                        if P > L:        # _search_pp would return None
-                            continue
-                        bound = self._candidate_bound(B, P)
-                        classes = {k: frontier.classify(k, bound)
-                                   for k in range(K) if active[k]}
-                        if any(c == "live" for c in classes.values()):
-                            run_list.append(P)
-                        elif all(c == "infeasible"
-                                 for c in classes.values()):
-                            self.stats["bp_pruned_infeasible"] += 1
-                        else:
-                            self.stats["bp_pruned_dominated"] += 1
-                            deferred.append(
-                                (P, [k for k, c in classes.items()
-                                     if c == "dominated"]))
-                    if pool is not None:
-                        wave = pool.run_many([(B, P) for P in run_list])
-                        for P in run_list:
-                            merge(P, wave[(B, P)])
-                    else:
-                        for P in run_list:
-                            merge(P, self._search_pp(B, P))
-                    # forced pass: a deferred candidate's feasibility may
-                    # be all that keeps a budget's OOM counter at zero
-                    for P, ks in deferred:
-                        if any(not found[k] for k in ks):
-                            self.stats["bp_forced"] += 1
-                            merge(P, self._search_pp(B, P))
+        consecutive_oom = [0] * K
+        for B in grid:
+            if not any(active):
+                break
+            found = [False] * K
+            for P in pp_degrees:
+                self.stats["bp_candidates"] += 1
+                plans = self._search_pp(B, P)
+                if plans is None:
+                    continue
                 for k in range(K):
-                    if not active[k]:
+                    if not active[k] or plans[k] is None:
                         continue
-                    consecutive_oom[k] = (0 if found[k]
-                                          else consecutive_oom[k] + 1)
-                    if consecutive_oom[k] >= 2:  # everything OOMs: stop
-                        active[k] = False        # growing B
-        finally:
-            self._batch_eval = False
-            if pool is not None:
-                pool.close()
+                    found[k] = True
+                    if (best[k] is None or plans[k].est_throughput
+                            > best[k].est_throughput):
+                        best[k] = plans[k]
+                        if verbose:
+                            print(f"[B={B} P={P} "
+                                  f"budget={axis[k]/2**30:.1f}G] "
+                                  f"tpt={plans[k].est_throughput:.2f} "
+                                  f"{plans[k].summary()}")
+            for k in range(K):
+                if not active[k]:
+                    continue
+                consecutive_oom[k] = 0 if found[k] else consecutive_oom[k] + 1
+                if consecutive_oom[k] >= 2:      # everything OOMs: stop
+                    active[k] = False            # growing B
         self.stats["search_seconds"] = _time.time() - t0
         for plan in best:
             if plan is not None:
                 plan.search_stats = dict(self.stats)
         return best
-
-    # ------------------------------------------------------------------
-    # parallel (B, P) fan-out (DESIGN.md §6)
-    # ------------------------------------------------------------------
-    def _make_shard(self) -> "GalvatronOptimizer":
-        """A worker-view of this optimizer: shares the immutable inputs
-        (specs, cost model, search space, budget axis) but writes stage-
-        search memo entries and telemetry into private shards, leaving the
-        parent's stage cache untouched until merge.
-
-        The table / reference / partition caches are shared *directly*:
-        their entries are deterministic, they are never iterated, and
-        CPython's GIL makes individual dict get/set atomic — so publishing
-        a freshly built cost table immediately spares every other worker
-        the same (expensive, budget-independent) build.  A lost race
-        merely rebuilds an identical value.
-        """
-        shard = copy.copy(self)
-        shard.stats = {k: (0.0 if k == "search_seconds" else 0)
-                       for k in self.stats}
-        shard._stage_cache = _ShardCache(self._stage_cache)
-        return shard
-
-    def _merge_shard(self, shard: "GalvatronOptimizer") -> None:
-        """Fold a worker shard back into the shared memo + telemetry.
-        ``update()`` on a shard only sees its local writes; counters are
-        summed so hits + misses == lookups holds across the merged stats."""
-        for k, v in shard.stats.items():
-            if k != "search_seconds":
-                self.stats[k] += v
-        self._stage_cache.update(shard._stage_cache)
-
-    def _merge_process_result(self, P: int, writes: Dict, stats: Dict) -> None:
-        """Fold one process-worker task back into the parent.
-
-        ``writes`` are the worker's stage-cache entries with the worker-
-        local strategy-set id *stripped* — ``strategy_set_id`` is an
-        insertion-order intern counter, so the worker's ids need not match
-        the parent's numbering; the parent re-keys every entry under its
-        own id for ``P`` (all writes of one (B, P) task share that one
-        strategy set).  Counters are summed, so hits + misses == lookups
-        holds across the merged stats."""
-        for k, v in stats.items():
-            if k in self.stats and k != "search_seconds":
-                self.stats[k] += v
-        if writes and self.cfg.enable_stage_cache:
-            _, sid = self._strategies_for(P)
-            for k, v in writes.items():
-                self._stage_cache[k + (sid,)] = v
-
-
-class _CandidatePool:
-    """Fan independent (B, P) outer candidates over an executor.
-
-    ``"threads"``: workers are shard views of the parent (shared memo
-    caches, private stage-cache shard + telemetry, merged as each task
-    completes — DESIGN.md §6).  ``"processes"``: each worker process
-    builds its own :class:`GalvatronOptimizer` from the parent's picklable
-    constructor arguments (with the serial backend pinned); tasks return
-    plans, stage-cache writes and a telemetry delta, which the parent
-    merges via ``_merge_process_result``.  Stage-search results are
-    deterministic functions of their inputs, so any completion
-    interleaving yields the same plans as the serial sweep.
-    """
-
-    def __init__(self, opt: GalvatronOptimizer, backend: str,
-                 max_workers: Optional[int]):
-        self._opt = opt
-        self._procs = backend == "processes"
-        # one worker per core: the DP is a stream of small NumPy calls, so
-        # oversubscription (the executor's cpu+4 default) turns GIL
-        # hand-offs into a convoy and *slows the thread sweep several-fold*
-        n = max_workers or os.cpu_count() or 2
-        if self._procs:
-            worker_cfg = dataclasses.replace(
-                opt.cfg, search_backend="serial", prune_batch_axis=False,
-                jobs=None)
-            self._pool = ProcessPoolExecutor(
-                max_workers=n, initializer=_process_worker_init,
-                initargs=(opt.specs, opt.cluster, worker_cfg,
-                          opt._cost_config, dict(opt.cost.profiled_times),
-                          opt._budget_axis))
-        else:
-            self._pool = ThreadPoolExecutor(max_workers=n)
-
-    def run_many(self, bps: Sequence[Tuple[int, int]]
-                 ) -> Dict[Tuple[int, int],
-                           Optional[List[Optional[ParallelPlan]]]]:
-        """Run candidates, merging caches/telemetry into the parent as
-        each completes (later tasks then reuse earlier finishers' work)."""
-        out: Dict[Tuple[int, int],
-                  Optional[List[Optional[ParallelPlan]]]] = {}
-        if not bps:
-            return out
-        opt = self._opt
-        if self._procs:
-            futures = [self._pool.submit(_process_worker_run, bp)
-                       for bp in bps]
-            for fut in as_completed(futures):
-                bp, plans, writes, stats = fut.result()
-                out[bp] = plans
-                opt._merge_process_result(bp[1], writes, stats)
-            return out
-
-        def run(bp: Tuple[int, int]):
-            shard = opt._make_shard()
-            return bp, shard._search_pp(*bp), shard
-
-        futures = [self._pool.submit(run, bp) for bp in bps]
-        for fut in as_completed(futures):
-            bp, plans, shard = fut.result()
-            out[bp] = plans
-            opt._merge_shard(shard)
-        return out
-
-    def close(self) -> None:
-        self._pool.shutdown()
-
-
-# ---- process-pool worker side (module-level for picklability) ------------
-
-_WORKER: Optional[GalvatronOptimizer] = None
-
-
-def _process_worker_init(specs, cluster, config, cost_config,
-                         profiled_times, axis) -> None:
-    """Build the worker-resident optimizer once per process; tasks then
-    share its memo caches for the worker's lifetime."""
-    global _WORKER
-    _WORKER = GalvatronOptimizer(specs, cluster, config, cost_config,
-                                 profiled_times or None)
-    _WORKER._set_budget_axis(tuple(axis))
-
-
-def _process_worker_run(bp: Tuple[int, int]):
-    """One (B, P) candidate in a worker process.
-
-    Runs on a shard (exactly like a thread worker) so the task's fresh
-    stage-cache writes and telemetry delta are cleanly separated, then
-    folds the shard into the worker-resident optimizer for intra-worker
-    reuse.  Returned cache keys have the worker-local strategy-set id
-    stripped (see ``_merge_process_result``); every write of the task
-    carries the same id — ``_search_pp`` resolves the strategy set once.
-    """
-    shard = _WORKER._make_shard()
-    plans = shard._search_pp(*bp)
-    writes = {k[:-1]: v for k, v in shard._stage_cache.items()}
-    stats = {k: v for k, v in shard.stats.items() if k != "search_seconds"}
-    _WORKER._merge_shard(shard)
-    return bp, plans, writes, stats
 
 
 # --------------------------------------------------------------------------

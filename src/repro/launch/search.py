@@ -10,22 +10,18 @@ budget.
         --cluster 8x-rtx-titan-pcie --budget-sweep 4,6,...,18 \\
         --out frontier.json
 
-    # assigned architecture on a TPU pod, process-pool (B, P) fan-out
+    # assigned architecture on a TPU pod, schedule axis searched too
     PYTHONPATH=src python -m repro.launch.search --arch qwen3-4b --seq 2048 \\
         --cluster tpu-v5e-pod-256 --budget-sweep 8,10,12,16 \\
-        --backend processes --jobs 8
+        --schedules 1f1b,1f1b-interleaved,zb-h1
 
 ``--budget-sweep`` takes GB values: an explicit comma list (``4,6,8``) or an
 arithmetic ellipsis ``a,b,...,z`` expanded with step ``b - a`` (so
 ``8,16,...,80`` means 8, 16, 24, …, 80).  The frontier (budgets, plans,
 predicted throughputs, knee points) is printed as a table and written as
 JSON via ``PlanFrontier.dumps`` when ``--out`` is given; a single-budget
-run writes the plan JSON instead.  ``--backend`` picks how the independent
-(B, P) outer candidates execute (serial / threads / processes pools /
-vectorized stacked-DP batching; ``--jobs`` sizes the pools) and
-frontier-guided batch-axis pruning is on unless ``--no-prune`` — every
-combination returns byte-identical plans with aggregated cache + pruning
-telemetry in the summary line (docs/search.md).
+run writes the plan JSON instead.  The summary line carries the search time
+and the stage-cache telemetry (docs/search.md).
 
 The model comes from ``--arch`` (an assigned architecture id, searched at
 ``--seq``) or ``--model`` (a paper evaluation model, fixed geometry).  The
@@ -40,7 +36,7 @@ import sys
 from typing import List
 
 from repro.core import (CLUSTERS, GalvatronOptimizer, galvatron_variant)
-from repro.core.optimizer import SEARCH_BACKENDS, normalize_batch_grid
+from repro.core.optimizer import normalize_batch_grid
 
 GB = 1024 ** 3
 
@@ -151,11 +147,6 @@ def build_optimizer(specs, cluster, args) -> GalvatronOptimizer:
         ocfg.max_pp = args.max_pp
     if args.schedules:
         ocfg.schedules = tuple(args.schedules.split(","))
-    if getattr(args, "backend", ""):
-        ocfg.search_backend = args.backend
-    if getattr(args, "jobs", 0):
-        ocfg.jobs = args.jobs
-    ocfg.prune_batch_axis = bool(getattr(args, "prune", False))
     if getattr(args, "sp", False):
         ocfg.use_sp = True
     if getattr(args, "max_sp", 0):
@@ -221,26 +212,6 @@ def main(argv=None) -> int:
                          "small budgets coarsely; anchor at the smallest "
                          "budget for dedicated-search resolution everywhere "
                          "at higher search cost")
-    ap.add_argument("--backend", default="", choices=("",) + SEARCH_BACKENDS,
-                    help="candidate execution backend: serial (the oracle), "
-                         "threads / processes (pooled (B, P) fan-out), or "
-                         "vectorized (stage DPs batched into one stacked "
-                         "NumPy evaluation).  Plans are byte-identical "
-                         "across backends (default: serial)")
-    ap.add_argument("--jobs", type=int, default=0,
-                    help="worker count for the threads/processes backends "
-                         "(default: one per core)")
-    ap.add_argument("--no-prune", dest="prune", action="store_false",
-                    help="disable frontier-guided batch-axis pruning "
-                         "(pruning skips (B, P) candidates whose certified "
-                         "optimistic bound is dominated or over-budget; "
-                         "plans are identical either way, it only saves "
-                         "search time)")
-    ap.add_argument("--parallel", action="store_true",
-                    help="fan (B, P) candidates across a thread pool "
-                         "(same as --backend threads)")
-    ap.add_argument("--workers", type=int, default=0,
-                    help="thread-pool size for --parallel (default: auto)")
     ap.add_argument("--variant", default="bmw",
                     help="galvatron_variant search-space preset: dp+tp / "
                          "dp+pp / galvatron / base / 1f1b-biobj / bmw")
@@ -302,7 +273,6 @@ def main(argv=None) -> int:
     print(f"model={model_name} ({len(specs)} layers)  cluster={cluster.name} "
           f"x{cluster.n_devices}")
 
-    workers = args.jobs or args.workers or None
     if args.slo_sweep:
         import json as _json
 
@@ -312,8 +282,7 @@ def main(argv=None) -> int:
         points, frontier = search.sweep_slos(
             slos, max_context=args.max_context,
             mean_context=args.mean_context or None,
-            ttft_slo_ms=args.ttft_slo,
-            backend=args.backend or None, verbose=args.verbose)
+            ttft_slo_ms=args.ttft_slo, verbose=args.verbose)
         for pt in points:
             if pt.plan is None or pt.plan.serving is None:
                 print(f"{pt.slo_ms:8.1f} ms  infeasible "
@@ -340,30 +309,21 @@ def main(argv=None) -> int:
                     for pt in points]}, indent=2)
     elif args.budget_sweep:
         budgets = parse_budget_sweep(args.budget_sweep)
-        frontier = opt.sweep_budgets(
-            budgets, parallel=args.parallel, max_workers=workers,
-            backend=args.backend or None, verbose=args.verbose)
+        frontier = opt.sweep_budgets(budgets, verbose=args.verbose)
         print(frontier.summary())
         knees = frontier.knee_points()
         print(f"{len(frontier.feasible_points())}/{len(frontier.points)} "
               f"budgets feasible, {len(knees)} knee points; "
               f"search {opt.stats['search_seconds']:.2f}s "
               f"({opt.stats['stage_cache_hits']:.0f} cache hits / "
-              f"{opt.stats['stage_cache_misses']:.0f} misses; "
-              f"{opt.stats['bp_pruned_infeasible']:.0f} candidates pruned "
-              f"over-budget + {opt.stats['bp_pruned_dominated']:.0f} "
-              f"dominated of {opt.stats['bp_candidates']:.0f}, "
-              f"{opt.stats['bp_forced']:.0f} forced)")
+              f"{opt.stats['stage_cache_misses']:.0f} misses)")
         emitted = [p.plan for p in frontier.feasible_points()]
         payload = frontier.dumps()
     else:
-        # a 1-point sweep is byte-identical to optimize() and honours the
-        # --backend / --parallel (B, P) fan-out
+        # a 1-point sweep is byte-identical to optimize() at that budget
         budget = args.budget * GB if args.budget else cluster.budget()
-        plan = opt.sweep_budgets(
-            [budget], parallel=args.parallel, max_workers=workers,
-            backend=args.backend or None,
-            verbose=args.verbose).points[0].plan
+        plan = opt.sweep_budgets([budget],
+                                 verbose=args.verbose).points[0].plan
         if plan is None:
             print(f"no feasible plan under {budget / GB:.1f} GB", file=sys.stderr)
             return 1

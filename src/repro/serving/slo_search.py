@@ -221,7 +221,6 @@ class ServingPlanSearch:
                    max_context: int = 2048,
                    mean_context: Optional[float] = None,
                    ttft_slo_ms: float = 0.0,
-                   backend: Optional[str] = None,
                    verbose: bool = False
                    ) -> Tuple[List[SloPoint], PlanFrontier]:
         """Walk the latency-SLO axis through ``sweep_budgets()``.
@@ -233,8 +232,7 @@ class ServingPlanSearch:
         mean_ctx = float(mean_context if mean_context is not None
                          else max_context / 2)
         budgets = [self.cost.slo_budget_bytes(s) for s in slo_ms_list]
-        frontier = self.opt.sweep_budgets(budgets, backend=backend,
-                                          verbose=verbose)
+        frontier = self.opt.sweep_budgets(budgets, verbose=verbose)
         points: List[SloPoint] = []
         for slo_ms, budget in zip(slo_ms_list, budgets):
             plan = frontier.plan_at(budget)

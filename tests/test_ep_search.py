@@ -298,8 +298,7 @@ def test_search_cli_wires_ep_flags():
     import argparse
     args = argparse.Namespace(
         variant="bmw", batch_grid="", n_bins=64, micro_candidates=2,
-        max_pp=0, schedules="", backend="", jobs=0, prune=True,
-        sp=False, max_sp=0, ep=True, max_ep=2,
+        max_pp=0, schedules="", sp=False, max_sp=0, ep=True, max_ep=2,
         min_samples_per_device=0.0)
     opt = build_optimizer([_moe_spec()], CLUSTER, args)
     assert opt.cfg.use_ep and opt.cfg.max_ep == 2

@@ -2,10 +2,9 @@
 
 The contract under test: ``sweep_budgets`` is a pure restructuring of N
 serial searches — byte-identical plans at every budget on a shared
-quantization grid, whether the sweep runs serially or fans (B, P)
-candidates across the thread pool; the frontier is monotone, feasible at
-its own budgets, and JSON round-trips; and ``clear_cache()`` returns the
-optimizer to a bit-exact cold start.
+quantization grid, with consistent cache telemetry; the frontier is
+monotone, feasible at its own budgets, and JSON round-trips; and
+``clear_cache()`` returns the optimizer to a bit-exact cold start.
 """
 import json
 
@@ -13,6 +12,7 @@ import pytest
 
 from repro.core import (GalvatronOptimizer, PlanFrontier, ParallelPlan,
                         Strategy, galvatron_variant, paper_8gpu)
+from repro.core.cost_model import CostModelConfig
 from repro.core.frontier import FrontierPoint
 from repro.core.layerspec import dense_layer
 
@@ -26,7 +26,7 @@ def _specs(n=8, seq=512, d=1024):
 
 
 def _mkopt(specs, cluster=None, *, budget=None, quant=None, variant="bmw",
-           **kw):
+           cost=None, **kw):
     cfg = galvatron_variant(variant)
     cfg.batch_grid = [8, 16]
     cfg.n_bins = 128
@@ -35,7 +35,7 @@ def _mkopt(specs, cluster=None, *, budget=None, quant=None, variant="bmw",
     cfg.quant_bytes = quant
     for k, v in kw.items():
         setattr(cfg, k, v)
-    return GalvatronOptimizer(specs, cluster or paper_8gpu(), cfg)
+    return GalvatronOptimizer(specs, cluster or paper_8gpu(), cfg, cost)
 
 
 def _canon(plan):
@@ -43,7 +43,7 @@ def _canon(plan):
 
 
 # ---------------------------------------------------------------------------
-# differential: sweep == serial optimize, serially and in parallel
+# differential: sweep == dedicated optimize() per budget
 # ---------------------------------------------------------------------------
 
 def test_single_point_sweep_matches_plain_optimize():
@@ -57,16 +57,37 @@ def test_single_point_sweep_matches_plain_optimize():
         assert _canon(frontier.points[0].plan) == _canon(serial)
 
 
-@pytest.mark.parametrize("variant", ["bmw", "base"])
-def test_sweep_matches_serial_grid(variant):
+@pytest.mark.parametrize("variant,seq,budgets,kw", [
+    pytest.param("bmw", 512, BUDGETS, {}, id="bmw"),
+    pytest.param("base", 512, BUDGETS, {}, id="base"),
+    # the schedule axis, interleaved at V 2
+    pytest.param("bmw", 512, BUDGETS,
+                 dict(schedules=("1f1b", "zb-h1", "1f1b-interleaved"),
+                      vpp_candidates=(2,)), id="schedules"),
+    # budgets tight enough without checkpointing that some points OOM
+    pytest.param("bmw", 512, [1.2 * GB, 2 * GB, 4 * GB],
+                 dict(allow_ckpt=False), id="tight-no-ckpt"),
+    # ring-attention SP at a long sequence, with the physical batch floor
+    pytest.param("bmw", 8192, BUDGETS,
+                 dict(use_sp=True, batch_grid=[1, 2, 4],
+                      cost=CostModelConfig(min_samples_per_device=1.0)),
+                 id="sp-long-seq"),
+])
+def test_sweep_matches_serial_grid(variant, seq, budgets, kw):
     """Every frontier point is byte-identical to an independent serial
     optimize() at that budget pinned to the sweep's quantization grid."""
-    specs = _specs(8)
-    frontier = _mkopt(specs, variant=variant).sweep_budgets(BUDGETS)
+    specs = _specs(8, seq=seq)
+    frontier = _mkopt(specs, variant=variant, **kw).sweep_budgets(budgets)
+    assert frontier.feasible_points(), "test setup: all budgets OOMed"
     for p in frontier.points:
-        serial = _mkopt(specs, budget=p.budget_bytes,
-                        quant=max(BUDGETS), variant=variant).optimize()
+        serial = _mkopt(specs, budget=p.budget_bytes, quant=max(budgets),
+                        variant=variant, **kw).optimize()
         assert _canon(p.plan) == _canon(serial), p.budget_bytes / GB
+    plans = [p.plan for p in frontier.feasible_points()]
+    if "schedules" in kw:
+        assert {p.schedule for p in plans} <= set(kw["schedules"])
+    if kw.get("use_sp"):
+        assert any(p.sp_degree > 1 for p in plans)
 
 
 def test_sweep_pinned_to_min_budget_matches_dedicated_searches():
@@ -85,23 +106,18 @@ def test_sweep_pinned_to_min_budget_matches_dedicated_searches():
     assert _canon(frontier.points[0].plan) == _canon(plain)
 
 
-def test_parallel_sweep_identical_and_stats_consistent():
-    specs = _specs(8)
-    serial_opt = _mkopt(specs)
-    parallel_opt = _mkopt(specs)
-    fr_serial = serial_opt.sweep_budgets(BUDGETS)
-    fr_parallel = parallel_opt.sweep_budgets(BUDGETS, parallel=True,
-                                             max_workers=3)
-    # plans byte-identical in any worker interleaving
-    for p, q in zip(fr_parallel.points, fr_serial.points):
-        assert _canon(p.plan) == _canon(q.plan)
-    assert fr_parallel == fr_serial      # search_stats excluded from eq
-    # aggregated cache counters stay consistent across the shard merges
-    for stats in (serial_opt.stats, parallel_opt.stats,
-                  fr_parallel.search_stats):
-        assert (stats["stage_cache_hits"] + stats["stage_cache_misses"]
-                == stats["stage_searches"])
-        assert stats["stage_cache_misses"] > 0
+def test_sweep_stats_consistent():
+    """The frontier carries the sweep's telemetry, and every stage search
+    is either a cache hit or a miss."""
+    opt = _mkopt(_specs(8))
+    frontier = opt.sweep_budgets(BUDGETS)
+    assert frontier.search_stats == opt.stats
+    stats = frontier.search_stats
+    assert (stats["stage_cache_hits"] + stats["stage_cache_misses"]
+            == stats["stage_searches"])
+    assert stats["stage_cache_misses"] > 0 and stats["stage_cache_hits"] > 0
+    # every (B, P) pair of the grid is visited once (nothing OOMs here)
+    assert stats["bp_candidates"] == 2 * len(opt.search_space.per_pp)
 
 
 # ---------------------------------------------------------------------------

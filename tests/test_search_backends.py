@@ -1,16 +1,21 @@
-"""Cluster-scale engine invariants: every execution backend (threads /
-processes / vectorized) and the frontier-guided batch-axis pruner must be a
-pure speedup — plans byte-identical to the serial oracle, telemetry
-consistent, caches auditable."""
+"""Outer-loop invariants of the serial search: a budget sweep's batch axis
+(strict-``>`` incumbent, per-budget two-consecutive-OOM stop) matches
+dedicated per-budget searches, the batch grid is canonicalized, the caches
+are auditable, and the plans the benchmark cells run are pinned."""
+import json
+import pathlib
+from collections import Counter
+
 import pytest
 
 from hypothesis import given, settings, strategies as st
 
-from repro.core import (GalvatronOptimizer, OptimizerConfig, SEARCH_BACKENDS,
+from repro.core import (GalvatronOptimizer, OptimizerConfig,
                         galvatron_variant, normalize_batch_grid, paper_8gpu)
 from repro.core.layerspec import dense_layer
 
 GB = 1024 ** 3
+REPO = pathlib.Path(__file__).resolve().parent.parent
 
 
 def _specs(n=8, seq=512, d=1024):
@@ -37,84 +42,54 @@ def _sweep(budgets, **kw):
     return dumps, dict(opt.stats), opt
 
 
-BUDGETS = [2.0 * GB, 4.0 * GB, 8.0 * GB]
+def _dedicated(budgets, **kw):
+    """One fresh optimize() per budget, on the sweep's quantization grid."""
+    out = []
+    for b in sorted(budgets):
+        opt = GalvatronOptimizer(
+            _specs(), paper_8gpu(),
+            _cfg(budget_bytes=b, quant_bytes=max(budgets), **kw))
+        out.append(opt.optimize())
+    return out
 
 
 # ---------------------------------------------------------------------------
-# differential: every backend x pruning == serial oracle, byte for byte
+# the batch axis: sweep == dedicated searches
 # ---------------------------------------------------------------------------
-
-@pytest.mark.parametrize("backend", ["threads", "processes", "vectorized"])
-@pytest.mark.parametrize("prune", [False, True])
-def test_backend_byte_identical_to_serial(backend, prune):
-    base, _, _ = _sweep(BUDGETS)
-    dumps, stats, _ = _sweep(BUDGETS, search_backend=backend,
-                             prune_batch_axis=prune, jobs=2)
-    assert dumps == base
-    assert any(d is not None for d in base)     # sweep is non-degenerate
-    assert stats["stage_cache_hits"] + stats["stage_cache_misses"] \
-        == stats["stage_searches"]
-
-
-def test_serial_pruned_identical_with_skips():
-    """Pruning alone (no pool): identical frontier, nonzero skip counts on a
-    sweep whose low budget is infeasible for the large batch sizes."""
-    budgets = [1.2 * GB, 2.0 * GB, 4.0 * GB]
-    base, base_stats, _ = _sweep(budgets, allow_ckpt=False)
-    dumps, stats, _ = _sweep(budgets, allow_ckpt=False, prune_batch_axis=True)
-    assert dumps == base
-    pruned = (stats["bp_pruned_infeasible"] + stats["bp_pruned_dominated"]
-              - stats["bp_forced"])
-    assert pruned > 0
-    # skipping must actually save inner DP work vs the unpruned serial run
-    assert stats["stage_searches"] < base_stats["stage_searches"]
-    assert stats["bound_evals"] > 0
-    assert stats["bp_candidates"] == base_stats["bp_candidates"]
-
 
 def test_two_oom_stop_trajectory_preserved():
     """Tight budgets where the batch axis hits the two-consecutive-OOM stop:
-    the pruner must reproduce the serial stopping point exactly (forced runs
-    exist for precisely this bookkeeping)."""
+    each budget of the sweep stops where a dedicated search at that budget
+    stops, and returns the same plan."""
     budgets = [1.0 * GB, 1.6 * GB]
-    base, _, _ = _sweep(budgets, allow_ckpt=False,
-                        batch_grid=[8, 16, 32, 64, 128, 256])
-    for backend in ("serial", "vectorized"):
-        dumps, stats, _ = _sweep(budgets, allow_ckpt=False,
-                                 batch_grid=[8, 16, 32, 64, 128, 256],
-                                 search_backend=backend,
-                                 prune_batch_axis=True)
-        assert dumps == base
-        assert stats["bp_forced"] >= 0
+    grid = [8, 16, 32, 64, 128, 256]
+    dumps, stats, opt = _sweep(budgets, allow_ckpt=False, batch_grid=grid)
+    plans = _dedicated(budgets, allow_ckpt=False, batch_grid=grid)
+    assert dumps == [p.canonical_dumps() if p is not None else None
+                     for p in plans]
+    assert all(d is not None for d in dumps)
+    # the stop fired: not every (B, P) pair of the grid was searched
+    assert stats["bp_candidates"] < len(grid) * len(opt.search_space.per_pp)
 
-
-# ---------------------------------------------------------------------------
-# property: pruning never drops the argmax-throughput batch size
-# ---------------------------------------------------------------------------
 
 @given(st.sampled_from([(8, 16), (8, 16, 24), (8, 16, 32, 48),
                         (8, 24, 40, 56, 72)]),
        st.sampled_from([(1.5, 3.0), (2.0, 4.0, 8.0), (1.2, 1.8, 2.6)]),
        st.booleans())
 @settings(max_examples=8, deadline=None)
-def test_pruning_keeps_argmax_batch(grid, budgets_gb, allow_ckpt):
+def test_sweep_keeps_argmax_batch(grid, budgets_gb, allow_ckpt):
+    """Per budget, the sweep's winning global batch size is the one a
+    search dedicated to that budget picks (None where both OOM)."""
     budgets = [b * GB for b in budgets_gb]
-    base, _, _ = _sweep(budgets, batch_grid=list(grid),
-                        allow_ckpt=allow_ckpt)
-    dumps, _, opt = _sweep(budgets, batch_grid=list(grid),
-                           allow_ckpt=allow_ckpt,
-                           search_backend="vectorized",
-                           prune_batch_axis=True)
-    # byte-identity subsumes it, but assert the paper-level property
-    # directly: per budget, the winning global batch size survives pruning
+    _, _, opt = _sweep(budgets, batch_grid=list(grid), allow_ckpt=allow_ckpt)
     frontier = opt.sweep_budgets(budgets)
-    for d, p in zip(base, frontier.points):
+    plans = _dedicated(budgets, batch_grid=list(grid), allow_ckpt=allow_ckpt)
+    for p, d in zip(frontier.points, plans):
         if d is None:
             assert p.plan is None
         else:
             assert p.plan is not None
-            assert f'"global_batch": {p.plan.global_batch}' in d
-    assert dumps == base
+            assert p.plan.global_batch == d.global_batch
 
 
 # ---------------------------------------------------------------------------
@@ -137,33 +112,15 @@ def test_config_normalizes_unsorted_grid():
     assert cfg.batch_grid == [8, 16, 64]
 
 
-def test_config_rejects_bad_backend():
-    with pytest.raises(ValueError, match="search_backend"):
-        OptimizerConfig(search_backend="gpu")
-    assert "serial" in SEARCH_BACKENDS
-
-
-def test_config_rejects_vectorized_without_vectorized_cost():
-    with pytest.raises(ValueError, match="vectorized"):
-        OptimizerConfig(search_backend="vectorized", vectorized_cost=False)
-
-
-def test_config_rejects_nonpositive_jobs():
-    with pytest.raises(ValueError, match="jobs"):
-        OptimizerConfig(jobs=0)
-
-
 # ---------------------------------------------------------------------------
-# cache audit: the new caches are registered with clear_cache()
+# cache audit: the coefficient cache is registered with clear_cache()
 # ---------------------------------------------------------------------------
 
 def test_clear_cache_covers_bound_and_coeff_caches():
-    _, _, opt = _sweep([1.5 * GB, 3.0 * GB], prune_batch_axis=True)
-    assert opt._bound_cache                     # pruning populated bounds
+    _, _, opt = _sweep([1.5 * GB, 3.0 * GB])
     opt.cost._group_coeffs("all_reduce", 4)
     assert opt.cost._coeff_cache                # coeff lookups memoized
     opt.clear_cache()
-    assert not opt._bound_cache
     assert not opt.cost._coeff_cache
     assert not opt._stage_cache
     assert all(v == 0 for v in opt.stats.values())
@@ -172,3 +129,51 @@ def test_clear_cache_covers_bound_and_coeff_caches():
     frontier = opt.sweep_budgets([1.5 * GB, 3.0 * GB])
     assert [p.plan.canonical_dumps() if p.plan is not None else None
             for p in frontier.points] == base
+
+
+# ---------------------------------------------------------------------------
+# the plans the training cells run (bench/configs at the cells' traffic)
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("name,seq,batch,chips,want", [
+    ("mamba2-370m", 2048, 8, 1, dict(
+        pp_degree=1, schedule="1f1b", n_micro=2, vpp_degree=1,
+        partition=[50],
+        counts={(1, 1, False): 17, (1, 1, True): 33},
+        model_axis=1, tp=False, zero=False, remat=(True,),
+        est_throughput=14.379039064696412)),
+    ("qwen3-4b-l9", 4096, 16, 4, dict(
+        pp_degree=2, schedule="1f1b", n_micro=4, vpp_degree=1,
+        partition=[7, 4],
+        counts={(1, 1, False): 1, (1, 1, True): 1, (1, 2, True): 2,
+                (2, 1, False): 2, (2, 1, True): 5},
+        model_axis=2, tp=True, zero=True, remat=(True,),
+        est_throughput=7.005043281101456)),
+])
+def test_search_plan_pins_bench_cells(name, seq, batch, chips, want):
+    """``launch/train.py::search_plan`` on a benchmark configuration at its
+    cell's seq, batch and chips returns the plan, mesh axis and policy
+    recorded here."""
+    from repro.configs import get_config
+    from repro.launch.train import search_plan
+    from repro.runtime.plan_bridge import model_axis_size, policy_from_plan
+    from repro.runtime.sharding import ShardPolicy
+
+    config = json.loads((REPO / "bench" / "configs" / f"{name}.json")
+                        .read_text())
+    cfg = get_config(config["program_arch"]).with_(
+        **config.get("program_overrides", {}))
+    plan = search_plan(cfg, seq, batch, n_devices=chips)
+    assert (plan.pp_degree, plan.schedule, plan.n_micro, plan.vpp_degree,
+            plan.partition) == (want["pp_degree"], want["schedule"],
+                                want["n_micro"], want["vpp_degree"],
+                                want["partition"])
+    assert Counter((s.tp, s.sdp, s.ckpt)
+                   for s in plan.strategies) == want["counts"]
+    assert model_axis_size(plan) == want["model_axis"]
+    assert policy_from_plan(cfg, plan) == ShardPolicy(
+        tp=want["tp"], zero=want["zero"], remat_segments=want["remat"],
+        shard_cache_seq=True, expert_axis="model", seq_shard=False,
+        sp_degree=1, ep_degree=1)
+    assert plan.est_throughput == pytest.approx(want["est_throughput"],
+                                                rel=1e-12)
