@@ -13,6 +13,7 @@ from typing import Optional
 import jax
 
 from . import flash_attention as _fa
+from . import paged_attention as _pa
 from . import ring_attention as _ra
 from . import rmsnorm as _rn
 from . import ssd_scan as _ssd
@@ -28,6 +29,13 @@ def flash_attention(q, k, v, *, causal: bool = True,
     return _fa.flash_attention(q, k, v, causal=causal, window=window,
                                block_q=block_q, block_k=block_k,
                                interpret=_interpret())
+
+
+def paged_attention(q, k_pool, v_pool, k_new, v_new, layer, page_rows,
+                    lengths):
+    """One-token decode attention over the lanes' live pool pages."""
+    return _pa.paged_attention(q, k_pool, v_pool, k_new, v_new, layer,
+                               page_rows, lengths, interpret=_interpret())
 
 
 def ring_flash_attention(q, k, v, *, axis_name: str = "seq", axis_size: int,

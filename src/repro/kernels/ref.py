@@ -34,6 +34,31 @@ def flash_attention_ref(q: jax.Array, k: jax.Array, v: jax.Array, *,
     return out.reshape(B, S, H, dh).astype(q.dtype)
 
 
+def paged_attention_ref(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
+                        k_new: jax.Array, v_new: jax.Array, layer,
+                        page_rows: jax.Array, lengths: jax.Array) -> jax.Array:
+    """q (B,KV,G,dh) decode attention over each lane's first ``lengths``
+    pooled positions of ``layer`` (pools (n_layers,N,psz,KV,dh), pages
+    ``page_rows`` (B,P)) plus its new token ``k_new``/``v_new`` (B,KV,dh)."""
+    B, KV, G, dh = q.shape
+    psz, P = k_pool.shape[2], page_rows.shape[1]
+    rows = jnp.where(page_rows < 0, 0, page_rows)
+
+    def lane_view(pool, new):
+        seq = pool[layer][rows].reshape(B, P * psz, KV, dh)
+        return jnp.concatenate([seq, new[:, None]], 1).astype(jnp.float32)
+
+    k, v = lane_view(k_pool, k_new), lane_view(v_pool, v_new)
+    pos = jnp.arange(P * psz + 1)
+    valid = ((pos[None, :] < lengths[:, None])
+             | (pos[None, :] == P * psz))                   # (B, T+1)
+    scores = jnp.einsum("bkgd,btkd->bkgt", q.astype(jnp.float32), k)
+    scores = scores / jnp.sqrt(jnp.float32(dh))
+    scores = jnp.where(valid[:, None, None, :], scores, -1e30)
+    probs = jax.nn.softmax(scores, axis=-1)
+    return jnp.einsum("bkgt,btkd->bkgd", probs, v).astype(q.dtype)
+
+
 def ssd_scan_ref(x: jax.Array, dt: jax.Array, A: jax.Array, Bm: jax.Array,
                  Cm: jax.Array, chunk: int = 64) -> jax.Array:
     """Chunked SSD oracle — delegates to the model-layer implementation

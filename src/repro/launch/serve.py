@@ -140,7 +140,9 @@ def serve_paged(cfg, requests: List[Request], ecfg, *,
               f"({summ['tok_per_s']:.1f} tok/s, "
               f"{summ['decode_steps']} decode steps, "
               f"{summ['prefill_chunks']} prefill chunks, "
-              f"peak page occupancy {summ['page_occupancy_max']:.2f})")
+              f"peak page occupancy {summ['page_occupancy_max']:.2f}; "
+              f"decode attention {summ['decode_attn']}, live page share "
+              f"{summ['live_page_share']:.3f})")
         phases = ", ".join(f"{k} {v:.3f}s/{summ['phase_n'][k]}"
                            for k, v in sorted(summ["phase_s"].items()))
         print(f"host: {phases}; {summ['host_syncs']} host syncs, "

@@ -26,14 +26,23 @@ _RESOLVED: Optional[Set[str]] = None
 @contextlib.contextmanager
 def recording_attention():
     """Yield the set of inner kernels (``flash``, ``chunked``, ``ref``,
-    ``ring``) that :func:`attention` resolves to while a step is traced
-    under this context — what a trainer reports of its layers."""
+    ``ring``) that :func:`attention` resolves to, and the decode attention
+    (``paged_kernel``, ``gather``) a paged decode step resolves to, while
+    a step is traced under this context — what a trainer reports of its
+    layers and a server of its decode step."""
     global _RESOLVED
     outer, _RESOLVED = _RESOLVED, set()
     try:
         yield _RESOLVED
     finally:
         _RESOLVED = outer
+
+
+def record_attention_impl(impl: str) -> None:
+    """Note ``impl`` in the set an enclosing :func:`recording_attention`
+    yields (no-op outside one)."""
+    if _RESOLVED is not None:
+        _RESOLVED.add(impl)
 
 
 def init_attention(key, cfg: ModelConfig, *, d_model: Optional[int] = None,
@@ -218,8 +227,7 @@ def attention(p, x: jax.Array, positions: jax.Array, cfg: ModelConfig, *,
     q, k, v = _project_qkv(p, x, cfg, positions)
     if impl == "auto":
         impl = resolve_impl(S, cfg)
-    if _RESOLVED is not None:
-        _RESOLVED.add(impl)
+    record_attention_impl(impl)
     if impl == "ring":
         from repro.kernels.ops import ring_flash_attention as _ring
         out = _ring(q, k, v, causal=causal, window=window,
@@ -276,6 +284,19 @@ def attention_decode(p, x: jax.Array, cache: Dict[str, jax.Array],
     return out, {"k": new_k, "v": new_v}
 
 
+def _decode_slot(page_rows: jax.Array, L: jax.Array, N: int, psz: int
+                 ) -> Tuple[jax.Array, jax.Array]:
+    """Pool row and in-page offset of each lane's new token at position
+    ``L``: (page_rows[lane, L // psz], L % psz).  Unassigned pages and
+    inactive lanes (L < 0) route to row N, out of bounds, so a
+    ``mode="drop"`` scatter skips them."""
+    P = page_rows.shape[1]
+    pi = jnp.clip(L // psz, 0, P - 1)
+    page = jnp.take_along_axis(page_rows, pi[:, None], axis=1)[:, 0]  # (B,)
+    page = jnp.where((page < 0) | (L < 0) | (L // psz >= P), N, page)
+    return page, jnp.clip(L % psz, 0, psz - 1)
+
+
 def attention_decode_paged(p, x: jax.Array, pool: Dict[str, jax.Array],
                            page_rows: jax.Array, lengths: jax.Array,
                            cfg: ModelConfig, *,
@@ -302,13 +323,7 @@ def attention_decode_paged(p, x: jax.Array, pool: Dict[str, jax.Array],
     P = page_rows.shape[1]
     L = lengths.astype(jnp.int32)
     q, k, v = _project_qkv(p, x, cfg, jnp.maximum(L, 0).reshape(B, 1))
-    # scatter the new token at (page_rows[lane, L // psz], L % psz);
-    # unassigned pages / inactive lanes route to row N (out of bounds)
-    # and the write is dropped
-    pi = jnp.clip(L // psz, 0, P - 1)
-    page = jnp.take_along_axis(page_rows, pi[:, None], axis=1)[:, 0]  # (B,)
-    page = jnp.where((page < 0) | (L < 0) | (L // psz >= P), N, page)
-    off = jnp.clip(L % psz, 0, psz - 1)
+    page, off = _decode_slot(page_rows, L, N, psz)
     new_k = pool["k"].at[page, off].set(
         k[:, 0].astype(pool["k"].dtype), mode="drop")
     new_v = pool["v"].at[page, off].set(
@@ -332,6 +347,65 @@ def attention_decode_paged(p, x: jax.Array, pool: Dict[str, jax.Array],
     out = jnp.einsum("bkgst,btkd->bskgd", probs, gv.astype(jnp.float32))
     out = out.reshape(B, 1, cfg.q_dim).astype(x.dtype) @ p["wo"]
     return out, {"k": new_k, "v": new_v}
+
+
+def resolve_decode_impl(cfg: ModelConfig, page_size: int, *,
+                        window: Optional[int] = None,
+                        pools_sharded: bool = False) -> str:
+    """The decode attention :func:`paged_decode_step` takes:
+    ``paged_kernel`` (the Pallas kernel reading live pages in place,
+    ``kernels/paged_attention.py``) on TPU outside ``force_unroll`` when
+    the head dim fills the 128 lanes, a page's ``page_size · KV`` rows
+    fill whole 8-row tiles, the pools are not sharded and there is no
+    sliding window; else ``gather`` (:func:`attention_decode_paged`)."""
+    if (jax.default_backend() == "tpu" and not unroll_scans()
+            and cfg.dh % 128 == 0 and (page_size * cfg.n_kv_heads) % 8 == 0
+            and not pools_sharded and window is None):
+        return "paged_kernel"
+    return "gather"
+
+
+def attention_decode_paged_in_place(p, x: jax.Array,
+                                    pools: Dict[str, jax.Array],
+                                    layer: jax.Array, page_rows: jax.Array,
+                                    lengths: jax.Array, cfg: ModelConfig
+                                    ) -> Tuple[jax.Array, Tuple]:
+    """One-token decode that reads the paged K/V in place and writes
+    nothing.
+
+    ``pools["k"/"v"]``: the stacked pools (n_layers, N, psz, KV, dh) of
+    the whole layer stack, read at ``layer`` by the paged-attention kernel
+    (only each lane's live pages, no gather, no per-layer slice).  Returns
+    the attention output and this token's ``(k_new, v_new)`` (B, KV, dh)
+    for :func:`write_decode_tokens` to store after the layer scan.  Same
+    semantics as :func:`attention_decode_paged`, whose pool it leaves
+    unwritten."""
+    from repro.kernels.ops import paged_attention as _paged
+    B = x.shape[0]
+    L = lengths.astype(jnp.int32)
+    dt = pools["k"].dtype
+    q, k, v = _project_qkv(p, x, cfg, jnp.maximum(L, 0).reshape(B, 1))
+    KV = cfg.n_kv_heads
+    k_new, v_new = k[:, 0].astype(dt), v[:, 0].astype(dt)
+    out = _paged(q.reshape(B, KV, cfg.n_heads // KV, cfg.dh), pools["k"],
+                 pools["v"], k_new, v_new, layer, page_rows, L)
+    out = out.reshape(B, 1, cfg.q_dim).astype(x.dtype) @ p["wo"]
+    return out, (k_new, v_new)
+
+
+def write_decode_tokens(pools: Dict[str, jax.Array], k_new: jax.Array,
+                        v_new: jax.Array, page_rows: jax.Array,
+                        lengths: jax.Array) -> Dict[str, jax.Array]:
+    """Store every layer's new token (k_new/v_new (n_layers, B, KV, dh))
+    in the stacked pools at each lane's (page, offset), one scatter per
+    pool; inactive lanes' writes are dropped, as in
+    :func:`attention_decode_paged`."""
+    n_layers, N, psz = pools["k"].shape[:3]
+    page, off = _decode_slot(page_rows, lengths.astype(jnp.int32), N, psz)
+    layer = jnp.arange(n_layers)[:, None]
+    return {name: pools[name].at[layer, page[None], off[None]].set(
+                new, mode="drop")
+            for name, new in (("k", k_new), ("v", v_new))}
 
 
 def attention_prefill_paged(p, x: jax.Array, pool: Dict[str, jax.Array],

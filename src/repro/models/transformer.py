@@ -25,8 +25,10 @@ import jax
 import jax.numpy as jnp
 
 from .attention import (attention, attention_decode, attention_decode_paged,
+                        attention_decode_paged_in_place,
                         attention_prefill_paged, init_attention,
-                        init_kv_cache, init_page_pool)
+                        init_kv_cache, init_page_pool, record_attention_impl,
+                        resolve_decode_impl, write_decode_tokens)
 from .common import ModelConfig
 from .flags import constrain_batch, constrain_batch_only, scan_unroll
 from .embedding import embed, init_embedding, init_projector, project
@@ -385,25 +387,28 @@ def init_paged_state(cfg: ModelConfig, n_pages: int, page_size: int,
     return {"stacks": stacks}
 
 
-def _paged_scan(params, pools, x, cfg, attn_fn):
-    """Scan ``attn_fn`` + FFN over each stack; returns (x, new pools)."""
-    new_stacks = []
-    for (kind, _), stack_params, pstack in zip(
-            build_stacks(cfg), params["stacks"], pools["stacks"]):
+def _paged_block(lp, h, cfg: ModelConfig, attn_fn):
+    """One layer of a paged step: ``attn_fn(attn params, normed h)`` ->
+    (attention output, what the scan emits), then the FFN."""
+    a, out = attn_fn(lp["attn"], rms_norm(h, lp["ln1"], cfg.norm_eps))
+    h = h + a
+    hn = rms_norm(h, lp["ln2"], cfg.norm_eps)
+    if "mlp" in lp:
+        return h + swiglu_mlp(lp["mlp"], hn), out
+    y, _ = moe_ffn(lp["moe"], hn, cfg)
+    return h + y, out
 
-        def body(carry, inp):
-            h = carry
+
+def _paged_scan(params, pools, x, cfg, attn_fn):
+    """Scan ``attn_fn`` + FFN over each stack, each layer's pool as a scan
+    input and its updated pool as the output; returns (x, new pools)."""
+    new_stacks = []
+    for stack_params, pstack in zip(params["stacks"], pools["stacks"]):
+
+        def body(h, inp):
             lp, lpool = inp
-            hn = rms_norm(h, lp["ln1"], cfg.norm_eps)
-            a, new_pool = attn_fn(lp["attn"], hn, lpool)
-            h = h + a
-            hn = rms_norm(h, lp["ln2"], cfg.norm_eps)
-            if "mlp" in lp:
-                h = h + swiglu_mlp(lp["mlp"], hn)
-            else:
-                y, _ = moe_ffn(lp["moe"], hn, cfg)
-                h = h + y
-            return h, new_pool
+            return _paged_block(lp, h, cfg,
+                                lambda p, hn: attn_fn(p, hn, lpool))
 
         n_l = jax.tree_util.tree_leaves(stack_params)[0].shape[0]
         x, new_pool = jax.lax.scan(body, x, (stack_params, pstack),
@@ -412,21 +417,62 @@ def _paged_scan(params, pools, x, cfg, attn_fn):
     return x, {"stacks": new_stacks}
 
 
+def _paged_decode_in_place(params, pools, x, page_rows, lengths, cfg):
+    """The decode layers over pools read in place: each stack's pools are
+    a loop-invariant operand of the scan that the paged-attention kernel
+    reads at the layer index, the scan emits only each layer's new token
+    K/V, and one scatter a pool writes them after the scan into the
+    (donated) pools.  Returns (x, new pools)."""
+    new_stacks = []
+    for stack_params, pstack in zip(params["stacks"], pools["stacks"]):
+
+        def body(h, inp, pstack=pstack):
+            lp, layer = inp
+            return _paged_block(
+                lp, h, cfg, lambda p, hn: attention_decode_paged_in_place(
+                    p, hn, pstack, layer, page_rows, lengths, cfg))
+
+        n_l = jax.tree_util.tree_leaves(stack_params)[0].shape[0]
+        x, (k_new, v_new) = jax.lax.scan(
+            body, x, (stack_params, jnp.arange(n_l, dtype=jnp.int32)),
+            unroll=scan_unroll(n_l))
+        new_stacks.append(write_decode_tokens(pstack, k_new, v_new,
+                                              page_rows, lengths))
+    return x, {"stacks": new_stacks}
+
+
 def paged_decode_step(params, pools, token: jax.Array,
                       page_rows: jax.Array, lengths: jax.Array,
                       cfg: ModelConfig, *,
-                      window: Optional[int] = None) -> Tuple[jax.Array, Dict]:
+                      window: Optional[int] = None,
+                      pools_sharded: bool = False
+                      ) -> Tuple[jax.Array, Dict]:
     """One decode step on the paged KV cache.
 
     token (B,) -> logits (B, V) + new pools.  ``page_rows`` (B, P) /
     ``lengths`` (B,) come from the serving engine's page table (same table
-    for every layer; each layer owns its own pool rows)."""
+    for every layer; each layer owns its own pool rows).
+
+    The decode attention is what
+    :func:`~repro.models.attention.resolve_decode_impl` picks
+    (``pools_sharded``: the pools' sharding splits them over devices):
+    ``paged_kernel`` reads each lane's live pages in place and writes the
+    new tokens after the layer scan; ``gather`` gathers every lane's
+    pages in each layer and writes its token there.  The choice is
+    recorded under :func:`~repro.models.attention.recording_attention`."""
     x = embed(params["embed"], token)[:, None, :]
     win = window if window is not None else cfg.sliding_window
-    x, new_pools = _paged_scan(
-        params, pools, x, cfg,
-        lambda p, h, lpool: attention_decode_paged(
-            p, h, lpool, page_rows, lengths, cfg, window=win))
+    impl = resolve_decode_impl(cfg, pools["stacks"][0]["k"].shape[2],
+                               window=win, pools_sharded=pools_sharded)
+    record_attention_impl(impl)
+    if impl == "paged_kernel":
+        x, new_pools = _paged_decode_in_place(params, pools, x, page_rows,
+                                              lengths, cfg)
+    else:
+        x, new_pools = _paged_scan(
+            params, pools, x, cfg,
+            lambda p, h, lpool: attention_decode_paged(
+                p, h, lpool, page_rows, lengths, cfg, window=win))
     logits = _logits(params, x, cfg)[:, 0]
     return logits, new_pools
 

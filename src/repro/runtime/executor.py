@@ -172,6 +172,9 @@ def make_paged_decode_step(cfg: ModelConfig, mesh: Mesh, policy: ShardPolicy,
                            n_slots: int, n_pages: int, page_size: int,
                            pages_per_slot: int) -> BuiltStep:
     """One-token decode over the shared KV page pools (serving engine).
+    The decode attention resolves from the backend, the shapes and
+    whether ``policy`` shards the pools over ``mesh``
+    (:func:`repro.models.attention.resolve_decode_impl`).
 
     Signature of the built fn:
     ``(params, pools, token (B,), page_rows (B,P), lengths (B,))``
@@ -182,13 +185,16 @@ def make_paged_decode_step(cfg: ModelConfig, mesh: Mesh, policy: ShardPolicy,
     arows = jax.ShapeDtypeStruct((n_slots, pages_per_slot), jnp.int32)
     alens = jax.ShapeDtypeStruct((n_slots,), jnp.int32)
 
-    def step(params, pools, token, page_rows, lengths):
-        return paged_decode_step(params, pools, token, page_rows, lengths,
-                                 cfg)
-
     ps = param_shardings(aparams, mesh, policy)
     pls = paged_state_shardings(apools, mesh, policy)
     rep = NamedSharding(mesh, P())
+    pools_sharded = not all(s.is_fully_replicated
+                            for s in jax.tree.leaves(pls))
+
+    def step(params, pools, token, page_rows, lengths):
+        return paged_decode_step(params, pools, token, page_rows, lengths,
+                                 cfg, pools_sharded=pools_sharded)
+
     fn = jax.jit(step, in_shardings=(ps, pls, rep, rep, rep),
                  donate_argnums=(1,))
     return BuiltStep(fn=fn,

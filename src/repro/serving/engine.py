@@ -37,6 +37,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.models.attention import recording_attention
 from repro.models.common import ModelConfig
 from repro.models.transformer import supports_paged_decode
 from repro.runtime.executor import (make_paged_decode_step,
@@ -109,6 +110,8 @@ class ServingEngine:
         self.pools = init_paged_state(cfg, ecfg.n_pages, ecfg.page_size)
         self.state: PageState = self.pm.init()
         self.metrics = ServeMetrics()
+        # decode attention the decode step resolved to, once traced
+        self.decode_attn: Optional[str] = None
         # host-side per-slot bookkeeping
         self._slot_req: List[Optional[ServeRequest]] = \
             [None] * ecfg.decode_slots
@@ -254,14 +257,19 @@ class ServingEngine:
             for i, r in enumerate(self._slot_req):
                 if r is not None and ok[i]:
                     token[i] = r.tokens[-1]
-            logits, self.pools = self._decode(
-                self.params, self.pools, jnp.asarray(token),
-                jnp.asarray(self.state.page_rows), jnp.asarray(lengths))
+            with recording_attention() as seen:
+                logits, self.pools = self._decode(
+                    self.params, self.pools, jnp.asarray(token),
+                    jnp.asarray(self.state.page_rows), jnp.asarray(lengths))
+            if seen:                   # this call traced the decode step
+                self.decode_attn = ",".join(sorted(seen))
             nxt = self._host(jnp.argmax(logits, axis=-1))
         with m.span("page_table"):
             self.state = self.pm.advance(self.state, ok)
         with m.span("bookkeep"):
-            m.decode_steps += 1
+            psz = self.ecfg.page_size
+            m.decode_round(int(np.sum(-(-np.maximum(lengths, 0) // psz))),
+                           self.state.page_rows.size)
             tnow = time.perf_counter() - t0
             for i in range(self.ecfg.decode_slots):
                 if not ok[i]:
@@ -303,6 +311,7 @@ class ServingEngine:
         finally:
             jax.monitoring.unregister_event_duration_listener(m.on_duration)
         m.wall_s = time.perf_counter() - t0
+        m.decode_attn = self.decode_attn
         return m
 
     def _round(self, queue: Deque[ServeRequest], t0: float) -> None:

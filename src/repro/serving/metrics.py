@@ -114,6 +114,13 @@ class ServeMetrics:
     # host phases: own seconds and calls per ``serve.<phase>`` span
     phase_s: Dict[str, float] = dataclasses.field(default_factory=dict)
     phase_n: Dict[str, int] = dataclasses.field(default_factory=dict)
+    # decode attention the decode step resolved to when it was traced
+    # (``paged_kernel`` or ``gather``; None before any decode round)
+    decode_attn: Optional[str] = None
+    # mean over decode rounds of the pages the paged kernel fetches (each
+    # stepping lane's ceil(L / page size)) over the pages the gather path
+    # reads (every lane's reserved pages)
+    live_page_share: float = 0.0
     host_syncs: int = 0                      # device -> host reads
     compiles: int = 0                        # backend compiles in run()
     compile_s: float = 0.0
@@ -131,6 +138,13 @@ class ServeMetrics:
         self.page_occupancy_max = max(self.page_occupancy_max, occupancy)
         self.page_occupancy_mean += ((occupancy - self.page_occupancy_mean)
                                      / self.samples)
+
+    def decode_round(self, live_pages: int, reserved_pages: int) -> None:
+        """One decode round: count it and fold its live page share into
+        the running mean."""
+        self.decode_steps += 1
+        self.live_page_share += ((live_pages / reserved_pages
+                                  - self.live_page_share) / self.decode_steps)
 
     def on_duration(self, event: str, duration: float, **_) -> None:
         """``jax.monitoring`` duration listener: counts backend compiles."""
@@ -162,6 +176,8 @@ class ServeMetrics:
             "queue_depth_max": self.queue_depth_max,
             "page_occupancy_mean": self.page_occupancy_mean,
             "page_occupancy_max": self.page_occupancy_max,
+            "decode_attn": self.decode_attn,
+            "live_page_share": self.live_page_share,
             "host_syncs": self.host_syncs,
             "compiles": self.compiles,
             "compile_s": self.compile_s,

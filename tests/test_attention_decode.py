@@ -175,3 +175,60 @@ def test_paged_prefill_then_decode_matches_dense():
             np.testing.assert_allclose(
                 np.asarray(chunk[lane:lane + 1, t:t + 1]),
                 np.asarray(ref_t), atol=1e-5, rtol=1e-5)
+
+
+@pytest.mark.parametrize("arch,zero_wo", [("qwen3-4b", True),
+                                          ("qwen3-4b", False),
+                                          ("arctic-480b", True)])
+def test_paged_kernel_decode_step_matches_gather_step(arch, zero_wo,
+                                                      monkeypatch):
+    """The whole decode step on the kernel path (pools read in place by
+    the paged kernel in interpret mode, new tokens written after the layer
+    scan) against the gather path, over three rounds that read back what
+    the earlier rounds wrote, on lanes at ragged lengths with one
+    inactive.  With the attention output projection zeroed every layer
+    sees the same input on both paths, so the returned pools must be
+    equal bit for bit; with it, the logits of the active lanes agree
+    within tolerance.  The MoE case has two layer stacks (one dense
+    layer, then two MoE layers), each with its own pools."""
+    from repro.configs import get_config
+    from repro.models import transformer as T
+    cfg = get_config(arch).with_(
+        n_layers=3, d_model=64, n_heads=4, n_kv_heads=2, head_dim=128,
+        d_ff=128, vocab_size=64, dtype=jnp.float32)
+    if cfg.n_experts > 1:
+        cfg = cfg.with_(n_experts=4, first_k_dense=1)
+    params = T.init_lm(jax.random.PRNGKey(0), cfg)
+    if zero_wo:
+        for stack in params["stacks"]:
+            stack["attn"]["wo"] = jnp.zeros_like(stack["attn"]["wo"])
+    B, psz, P, N = 4, 8, 4, 20
+    rng = np.random.default_rng(1)
+    pools = {"stacks": [{
+        name: jnp.asarray(rng.standard_normal((n, N, psz, 2, 128)),
+                          jnp.float32) for name in "kv"}
+        for _, n in T.build_stacks(cfg)]}
+    rows = jnp.asarray(rng.permutation(N)[:B * P].reshape(B, P), jnp.int32)
+    lengths = jnp.array([0, 7, 13, -1], jnp.int32)
+    token = jnp.array([3, 5, 7, 9], jnp.int32)
+    step = {}
+    for impl in ("paged_kernel", "gather"):
+        monkeypatch.setattr(T, "resolve_decode_impl",
+                            lambda *a, impl=impl, **k: impl)
+        step[impl] = jax.jit(lambda pl, t, n: T.paged_decode_step(
+            params, pl, t, rows, n, cfg)).lower(
+                pools, token, lengths).compile()
+    got, want = pools, pools
+    live = np.asarray(lengths) >= 0
+    for _ in range(3):
+        lg, got = step["paged_kernel"](got, token, lengths)
+        lw, want = step["gather"](want, token, lengths)
+        if zero_wo:
+            for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+                np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        else:
+            np.testing.assert_allclose(np.asarray(lg)[live],
+                                       np.asarray(lw)[live],
+                                       atol=1e-4, rtol=1e-4)
+        token = jnp.argmax(lw, axis=-1).astype(jnp.int32)
+        lengths = jnp.where(lengths >= 0, lengths + 1, lengths)

@@ -114,6 +114,117 @@ def test_attention_auto_resolves_from_backend_shape_and_mesh(monkeypatch):
         assert A.resolve_impl(2048, _qwen_like(8, 4)) == "flash"
 
 
+def test_decode_attention_resolves_from_backend_shape_window_and_pools(
+        monkeypatch):
+    """The paged decode kernel on TPU at fitting shapes; the gather path
+    on CPU, under ``force_unroll``, at a head dim off the 128 lanes, at a
+    page of fewer than 8 rows, with a sliding window (the kernel does not
+    mask one) and when the pools are sharded over a mesh."""
+    from repro.models import attention as A
+    from repro.models.flags import force_unroll
+    cfg = _qwen_like(8, 4)
+    assert A.resolve_decode_impl(cfg, 16) == "gather"          # CPU
+    monkeypatch.setattr(A.jax, "default_backend", lambda: "tpu")
+    assert A.resolve_decode_impl(cfg, 16) == "paged_kernel"
+    assert A.resolve_decode_impl(_qwen_like(32, 8), 16) == "paged_kernel"
+    assert A.resolve_decode_impl(_qwen_like(8, 1), 8) == "paged_kernel"
+    with force_unroll():
+        assert A.resolve_decode_impl(cfg, 16) == "gather"
+    assert A.resolve_decode_impl(_qwen_like(8, 4, 64), 16) == "gather"
+    assert A.resolve_decode_impl(_qwen_like(8, 1), 4) == "gather"
+    assert A.resolve_decode_impl(cfg, 16, window=512) == "gather"
+    assert A.resolve_decode_impl(cfg, 16, pools_sharded=True) == "gather"
+
+
+def test_paged_decode_step_records_gather_on_cpu_with_a_window():
+    """What a server reports of its decode step: the resolved decode
+    attention, recorded when the step is traced."""
+    from repro.models.attention import recording_attention
+    from repro.models.transformer import (init_lm, init_paged_state,
+                                          paged_decode_step)
+    cfg = _qwen_like(8, 4).with_(n_layers=2)
+    params = jax.eval_shape(lambda: init_lm(jax.random.PRNGKey(0), cfg))
+    pools = jax.eval_shape(lambda: init_paged_state(cfg, 8, 16))
+    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)
+    for window in (None, 64):
+        with recording_attention() as seen:
+            jax.eval_shape(lambda p, pl, t, r, n: paged_decode_step(
+                p, pl, t, r, n, cfg, window=window),
+                params, pools, i32(2), i32(2, 4), i32(2))
+        assert seen == {"gather"}
+
+
+def _paged_case(rng, dtype, *, B, P, psz, KV, dh, n_layers=2):
+    """Random stacked pools with every row filled, and a page table whose
+    lanes sit at length 0, psz - 1, psz, mid-page, the cap's last
+    position and inactive (-1); each lane holds distinct pool rows for
+    the positions up to its new token's, -1 past them."""
+    lens = np.array([0, psz - 1, psz, psz + psz // 2 + 1, P * psz - 1, -1]
+                    [:B], np.int32)
+    pages = [-(-(int(L) + 1) // psz) if L >= 0 else 2 for L in lens]
+    N = sum(pages) + 3
+    perm = rng.permutation(N)
+    rows = np.full((B, P), -1, np.int32)
+    at = 0
+    for b, n in enumerate(pages):
+        rows[b, :n] = perm[at:at + n]
+        at += n
+    normal = lambda *s: jnp.asarray(rng.standard_normal(s), dtype)
+    pools = [normal(n_layers, N, psz, KV, dh) for _ in range(2)]
+    return pools, jnp.asarray(rows), jnp.asarray(lens), normal
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("psz", [8, 16])
+@pytest.mark.parametrize("G", [1, 4])
+def test_paged_attention_sweep(G, psz, dtype):
+    """The paged decode kernel (interpret mode, two pages a block so a
+    lane spans several blocks) against its oracle, and the in-place
+    decode attention built on it against the gather path: the same
+    output for every active lane, and the same pool once its new tokens
+    are written."""
+    from repro.kernels.paged_attention import paged_attention
+    from repro.kernels.ref import paged_attention_ref
+    from repro.models.attention import (attention_decode_paged,
+                                        attention_decode_paged_in_place,
+                                        init_attention, write_decode_tokens)
+    B, P, KV, dh = 6, 4, 2, 128
+    rng = np.random.default_rng(G * 100 + psz)
+    (kp, vp), rows, lens, normal = _paged_case(
+        rng, dtype, B=B, P=P, psz=psz, KV=KV, dh=dh)
+    q, kn, vn = normal(B, KV, G, dh), normal(B, KV, dh), normal(B, KV, dh)
+    for layer in (0, 1):
+        out = paged_attention(q, kp, vp, kn, vn, jnp.int32(layer), rows,
+                              lens, pages_per_block=2, interpret=True)
+        ref = paged_attention_ref(q, kp, vp, kn, vn, layer, rows, lens)
+        np.testing.assert_allclose(np.asarray(out, np.float32),
+                                   np.asarray(ref, np.float32),
+                                   atol=TOL[dtype], rtol=TOL[dtype])
+    # an inactive lane reads nothing: its output is its own value row
+    np.testing.assert_array_equal(
+        np.asarray(out[-1]), np.asarray(jnp.repeat(vn[-1][:, None], G, 1)))
+
+    cfg = _qwen_like(KV * G, KV).with_(dtype=dtype)
+    p = init_attention(jax.random.PRNGKey(G), cfg)
+    x = normal(B, 1, cfg.d_model)
+    pools = {"k": kp, "v": vp}
+    got, (k_new, v_new) = attention_decode_paged_in_place(
+        p, x, pools, jnp.int32(1), rows, lens, cfg)
+    want, pool1 = attention_decode_paged(
+        p, x, {"k": kp[1], "v": vp[1]}, rows, lens, cfg)
+    live = np.asarray(lens) >= 0
+    scale = float(np.abs(np.asarray(want, np.float32)).max())
+    np.testing.assert_allclose(np.asarray(got, np.float32)[live],
+                               np.asarray(want, np.float32)[live],
+                               atol=TOL[dtype] * scale, rtol=TOL[dtype])
+    zeros = jnp.zeros_like(k_new)
+    written = write_decode_tokens(pools, jnp.stack([zeros, k_new]),
+                                  jnp.stack([zeros, v_new]), rows, lens)
+    for name in "kv":
+        np.testing.assert_array_equal(np.asarray(written[name][1]),
+                                      np.asarray(pool1[name]))
+
+
 @pytest.mark.parametrize("S,impl,recorded", [
     (1024, "auto", {"chunked"}), (16, "auto", {"ref"}),
     (16, "flash", {"flash"})])

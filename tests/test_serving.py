@@ -256,6 +256,38 @@ def test_engine_reads_the_device_only_for_argmax():
     assert int(pm.used_pages(engine.state)) == 0
 
 
+def test_summary_reports_decode_attention_and_live_page_share():
+    """``decode_attn`` names what the decode step resolved to when it was
+    traced (the gather path on CPU), and stays reported by a later run
+    with fresh metrics; ``live_page_share`` is the mean over decode rounds
+    of each stepping lane's ceil(L / page size) pages over every lane's
+    reserved pages, as the page table handed to the step says."""
+    from repro.serving import EngineConfig, ServeRequest
+    from repro.serving.metrics import ServeMetrics
+
+    ecfg = EngineConfig(page_size=4, n_pages=12, decode_slots=3,
+                        max_context=16, prefill_batch=2, prefill_chunk=4)
+    engine = _tiny_engine(ecfg)
+    decode, shares = engine._decode, []
+
+    def recorded(params, pools, token, page_rows, lengths):
+        L = np.maximum(np.asarray(lengths), 0)
+        shares.append(np.sum(-(-L // 4)) / np.asarray(page_rows).size)
+        return decode(params, pools, token, page_rows, lengths)
+
+    engine._decode = recorded
+    for run in range(2):
+        shares.clear()
+        engine.metrics = ServeMetrics()
+        reqs = [ServeRequest(rid=f"r{i}", prompt=[3 + i] * (2 + 3 * i),
+                             max_new=6) for i in range(4)]
+        summ = engine.run(reqs).summary()
+        assert summ["decode_attn"] == "gather", run
+        assert summ["decode_steps"] == len(shares) > 0
+        assert 0 < summ["live_page_share"] < 1
+        assert summ["live_page_share"] == pytest.approx(np.mean(shares))
+
+
 def test_engine_rejects_oversized_prompt_and_unsupported_arch():
     from repro.launch.mesh import make_local_mesh
     from repro.models import init_lm

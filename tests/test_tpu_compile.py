@@ -137,3 +137,42 @@ def test_ssd_scan_mamba2_370m_widths(one_chip):
     compiled = jax.jit(lambda *a: ssd_scan(*a, chunk=64)
                        ).lower(x, dt, A, bc, bc).compile()
     _assert_kernel(compiled)
+
+
+def test_paged_decode_step_reads_the_pools_in_place(topo, monkeypatch):
+    """The serve cell's whole decode step (qwen3-4b widths, 36 layers, 24
+    lanes, 1,600 pages of 16, 128 pages a lane) resolved to the paged
+    kernel: no copy of a whole stacked pool, no gather of every lane's
+    reserved pages, and the donated pools aliased to the returned ones.
+    The described chip is not the default backend, so the test steers
+    the resolution to TPU and the kernel off interpret mode."""
+    import re
+    from repro.configs import get_config
+    from repro.kernels import ops
+    from repro.models import attention as A
+    from repro.runtime.executor import make_paged_decode_step
+    from repro.runtime.sharding import ShardPolicy
+    monkeypatch.setattr(ops, "_interpret", lambda: False)
+    monkeypatch.setattr(A.jax, "default_backend", lambda: "tpu")
+    mesh = Mesh(np.array(topo.devices[:1]).reshape(1, 1), ("data", "model"),
+                axis_types=(AxisType.Auto,) * 2)
+    cfg = get_config("qwen3-4b").with_(tie_embeddings=True)
+    with A.recording_attention() as seen:
+        built = make_paged_decode_step(cfg, mesh, ShardPolicy(tp=False,
+                                                              zero=False),
+                                       24, 1600, 16, 128)
+        compiled = built.fn.lower(*built.abstract_args).compile()
+    assert seen == {"paged_kernel"}
+    text = compiled.as_text()
+    _assert_kernel(compiled)
+    pool = "bf16[36,1600,16,8,128]"
+    assert not [l for l in text.splitlines()
+                if re.search(re.escape(pool) + r"\S* copy\(", l)]
+    assert "bf16[3072,16,8,128]" not in text
+    leaves = jax.tree.leaves(built.abstract_args)
+    pools = {i for i, x in enumerate(leaves)
+             if x.shape == (36, 1600, 16, 8, 128)}
+    assert len(pools) == 2
+    aliased = set(map(int, re.findall(r"\((\d+), \{\}, (?:may|must)-alias\)",
+                                      text.splitlines()[0])))
+    assert pools <= aliased
